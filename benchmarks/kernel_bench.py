@@ -329,7 +329,10 @@ def backend_rows(smoke: bool = False) -> list:
 
 
 def main() -> None:
+    from repro.backend import enable_compile_cache
     from repro.core.ubplan import plan_attention, plan_matmul, plan_ssd, plan_stencil
+
+    enable_compile_cache()
     from repro.kernels import ref
     from repro.kernels.flash_attention import flash_attention
     from repro.kernels.matmul import matmul

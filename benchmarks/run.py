@@ -14,6 +14,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 def main() -> None:
+    from repro.backend import enable_compile_cache
+
+    enable_compile_cache()
     if "--bench-smoke" in sys.argv[1:]:
         sys.exit(bench_smoke_check())
     if "--tune-smoke" in sys.argv[1:]:

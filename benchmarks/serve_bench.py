@@ -235,6 +235,9 @@ def serve_smoke_check(path: str | None = None) -> int:
 
 
 def main() -> None:
+    from repro.backend import enable_compile_cache
+
+    enable_compile_cache()
     if "--smoke" in sys.argv[1:]:
         sys.exit(serve_smoke_check())
     print(

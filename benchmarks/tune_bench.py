@@ -163,6 +163,9 @@ def tune_smoke_check(path: str | None = None) -> int:
 
 
 def main() -> None:
+    from repro.backend import enable_compile_cache
+
+    enable_compile_cache()
     if "--smoke" in sys.argv[1:]:
         sys.exit(tune_smoke_check())
     for row in tune_rows():

@@ -55,6 +55,7 @@ from .runner import (
     clear_pipeline_cache,
     compile_pipeline,
     drop_pipeline_cache_entry,
+    enable_compile_cache,
     max_abs_error,
     pipeline_cache_size,
     pipeline_cache_stats,
@@ -93,6 +94,7 @@ __all__ = [
     "scheduler_cost",
     "PallasPipeline",
     "compile_pipeline",
+    "enable_compile_cache",
     "plan_cache_key",
     "schedule_db_key",
     "TUNABLE_KEYS",

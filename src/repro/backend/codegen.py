@@ -23,7 +23,9 @@ executable ``pallas_call``:
 
 Column taps and reduction offsets stay *inside* the kernel as static slices
 of the delivered block or scratch panel (register-level shifts within a
-panel, the paper's Fig. 8a chain lifted from pixels to rows).
+panel, the paper's Fig. 8a chain lifted from pixels to rows); strided and
+traced-position taps are read through the ref instead, which is what the
+TPU compiler (Mosaic) can lower.
 """
 
 from __future__ import annotations
@@ -38,7 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.ubplan import KernelPlan, VMEM_BYTES
+from repro.core.ubplan import LANE, KernelPlan, VMEM_BYTES
 from repro.frontend.expr import BinOp, Const, Expr, FuncRef, IterVal, Select
 from repro.frontend.lower import NormalizedStage
 
@@ -136,6 +138,8 @@ class _StageCtx:
         self.step0 = 0
         self.stepk = 0
         self.stepj = 0
+        # view group -> (lane stride, tap starts) of a segmented view
+        self.lane_segments: Dict[int, Tuple[int, Tuple[int, ...]]] = {}
 
     def with_rows(self, rows: int) -> "_StageCtx":
         """A copy evaluating only the first ``rows`` rows of the panel."""
@@ -207,6 +211,72 @@ class _StageCtx:
         return out
 
 
+def _lane_segments(kg: KernelGroup) -> Dict[int, Tuple[int, Tuple[int, ...]]]:
+    """View groups whose last axis every tap reads at one stride ``s > 1``
+    from a static start, mapped to ``(s, starts)``.  Mosaic has no strided
+    load along lanes, so such a view is delivered as one segment per
+    start — the elements ``start, start + s, ...`` packed contiguously
+    from a lane-aligned offset (see :func:`_segment_width`) — and each tap
+    reads its segment whole.  Ring-fed views are left alone: their rings
+    keep the delivered layout."""
+    ring_fed = {i for r in kg.rings for i in (r.steady, r.prefix)}
+    seen: Dict[int, set] = {}
+    for sp in kg.stages:
+        for li, binding in enumerate(sp.view_binding):
+            if sp.load_kind[li] != "view":
+                continue
+            ax = sp.accesses[li].axes[-1]
+            for gi in set(binding.values()):
+                g = kg.groups[gi]
+                last = g.ndim - 1
+                spanned = (
+                    ax.pure_dim is not None
+                    and not ax.red_coeffs
+                    and last not in (g.blocked_axis, g.lane_axis, g.red_axis)
+                )
+                seen.setdefault(gi, set()).add(
+                    (ax.stride, ax.const - g.base[last]) if spanned else None
+                )
+    out: Dict[int, Tuple[int, Tuple[int, ...]]] = {}
+    for gi, taps in seen.items():
+        strides = {t[0] for t in taps if t is not None}
+        if gi in ring_fed or None in taps or len(strides) != 1:
+            continue
+        stride = strides.pop()
+        if stride > 1:
+            out[gi] = (stride, tuple(sorted(t[1] for t in taps)))
+    return out
+
+
+def _segment_width(span: int, stride: int) -> int:
+    """Lane width of one segment: the longest strided read of a ``span``
+    wide view, rounded up to whole 128-lane vregs so every segment starts
+    lane-aligned."""
+    longest = -(-span // stride)
+    return -(-longest // LANE) * LANE
+
+
+def _segmented(view: jax.Array, stride: int, starts: Tuple[int, ...]) -> jax.Array:
+    """``view`` with its last axis regrouped into one zero-padded segment
+    per start (see :func:`_lane_segments`)."""
+    w = _segment_width(view.shape[-1], stride)
+    lead = [(0, 0)] * (view.ndim - 1)
+    segs = [view[..., t::stride] for t in starts]
+    return jnp.concatenate(
+        [jnp.pad(v, lead + [(0, w - v.shape[-1])]) for v in segs], axis=-1
+    )
+
+
+def _span(start: int, n: int, stride: int) -> Tuple[object, object]:
+    """(ref index, value index) reading ``n`` elements from ``start`` at
+    ``stride``.  A unit-stride span slices the loaded block; a strided one
+    is read through the ref (``pl.ds`` with a stride), because Mosaic
+    cannot lower a strided slice of a loaded value."""
+    if stride == 1:
+        return slice(None), slice(start, start + n)
+    return pl.ds(start, n, stride), slice(None)
+
+
 def _tap(
     ctx: _StageCtx,
     refs,
@@ -219,11 +289,24 @@ def _tap(
     """Extract one load's value lattice — from a delivered view block, a
     cross-grid-step ring (input delivery or line-buffered intermediate), or
     an in-kernel scratch panel — and align it with the stage's output block
-    (transpose + broadcast axes)."""
+    (transpose + broadcast axes).
+
+    Each source axis gets a ref index (applied by the load) and a value
+    index (applied to the loaded block): strided spans and the traced
+    global reduction position of a resident operand are read through the
+    ref, every other offset slices the loaded value.  ``tags`` names the
+    pure dim of each axis the tap keeps (None for a kept unit axis)."""
     sp = ctx.sp
     la = sp.accesses[load_idx]
+    ref_idx: List[object] = [slice(None)] * len(la.axes)
     idx: List[object] = []
-    tags: List[str] = []
+    tags: List[Optional[str]] = []
+
+    def span(j: int, start: int, ep: int, stride: int, dim: str) -> None:
+        ref_idx[j], v = _span(start, ep, stride)
+        idx.append(v)
+        tags.append(dim)
+
     if sp.load_kind[load_idx] == "scratch":
         pname = sp.scratch_producer[load_idx]
         slot = la.axes[0].offset_at(rho) + shift
@@ -234,7 +317,7 @@ def _tap(
             # one column ring; the lane-shift panel starts ``lslot - lo``
             # columns in (the column analog of the row-ring tap below)
             lslot = la.axes[-1].offset_at(rho) + lshift
-            block = scratch[(pname, (slot, None))][...]
+            src = scratch[(pname, (slot, None))]
             lead: object = (
                 slice(None) if ctx.rows == ctx.bh else slice(0, ctx.rows)
             )
@@ -242,19 +325,19 @@ def _tap(
         elif plb is not None:
             # line-buffered producer: the per-shift panel lives at rows
             # [slot - lo, slot - lo + bh) of the persistent ring
-            block = scratch[(pname, None)][...]
+            src = scratch[(pname, None)]
             lead = slice(slot - plb.lo, slot - plb.lo + ctx.rows)
         elif ctx.lane:
             # lane-blocked producer: the (row, lane)-shift panel holds the
             # tap's bw columns exactly (lane offset baked into the slot);
             # a partial-width (warm-up) consumer takes the leading columns
             lslot = la.axes[-1].offset_at(rho) + lshift
-            block = scratch[(pname, (slot, lslot))][...]
+            src = scratch[(pname, (slot, lslot))]
             lead = slice(None) if ctx.rows == ctx.bh else slice(0, ctx.rows)
             if ctx.cols != ctx.bw:
                 lane_sl = slice(0, ctx.cols)
         else:
-            block = scratch[(pname, slot)][...]
+            src = scratch[(pname, slot)]
             lead = slice(None) if ctx.rows == ctx.bh else slice(0, ctx.rows)
         last = len(la.axes) - 1
         for j, ax in enumerate(la.axes):
@@ -265,10 +348,9 @@ def _tap(
                 idx.append(lane_sl)                 # the lane-blocked dim
                 tags.append(ax.pure_dim)
             elif ax.pure_dim is not None:
-                ep = ctx.extent(ax.pure_dim)
-                start = ax.offset_at(rho)           # scratch axes are zero-based
-                idx.append(slice(start, start + ax.stride * (ep - 1) + 1, ax.stride))
-                tags.append(ax.pure_dim)
+                # scratch axes are zero-based
+                span(j, ax.offset_at(rho), ctx.extent(ax.pure_dim),
+                     ax.stride, ax.pure_dim)
             else:
                 idx.append(ax.offset_at(rho))       # squeezed static index
     else:
@@ -287,7 +369,7 @@ def _tap(
             # row axis holds exactly this row step's bh delivered rows
             r_idx, t0 = ring_hit
             ring = ctx.kg.rings[r_idx]
-            block = scratch[(_RING, r_idx)][...]
+            src = scratch[(_RING, r_idx)]
             for j, ax in enumerate(la.axes):
                 if j == ring.axis:
                     idx.append(slice(t0, t0 + ctx.cols))
@@ -296,10 +378,8 @@ def _tap(
                     idx.append(slice(0, ctx.rows))
                     tags.append(ctx.d0)
                 elif ax.pure_dim is not None:
-                    ep = ctx.extent(ax.pure_dim)
-                    start = ax.offset_at(rho) - ring.base[j]
-                    idx.append(slice(start, start + ax.stride * (ep - 1) + 1, ax.stride))
-                    tags.append(ax.pure_dim)
+                    span(j, ax.offset_at(rho) - ring.base[j],
+                         ctx.extent(ax.pure_dim), ax.stride, ax.pure_dim)
                 else:
                     idx.append(ax.offset_at(rho) - ring.base[j])
         elif ring_hit is not None:
@@ -307,21 +387,22 @@ def _tap(
             # into the ring, which the emitter keeps aligned with the grid
             r_idx, t0 = ring_hit
             ring = ctx.kg.rings[r_idx]
-            block = scratch[(_RING, r_idx)][...]
+            src = scratch[(_RING, r_idx)]
             for j, ax in enumerate(la.axes):
                 if j == j0:
                     idx.append(slice(t0, t0 + ctx.rows))
                     tags.append(ctx.d0)
                 elif ax.pure_dim is not None:
-                    ep = ctx.extent(ax.pure_dim)
-                    start = ax.offset_at(rho) - ring.base[j]
-                    idx.append(slice(start, start + ax.stride * (ep - 1) + 1, ax.stride))
-                    tags.append(ax.pure_dim)
+                    span(j, ax.offset_at(rho) - ring.base[j],
+                         ctx.extent(ax.pure_dim), ax.stride, ax.pure_dim)
                 else:
                     idx.append(ax.offset_at(rho) - ring.base[j])
         else:
-            g = ctx.kg.groups[sp.view_binding[load_idx][key]]
-            block = refs[sp.view_binding[load_idx][key]][...]
+            gi = sp.view_binding[load_idx][key]
+            g = ctx.kg.groups[gi]
+            src = refs[gi]
+            seg = ctx.lane_segments.get(gi)
+            last = len(la.axes) - 1
             for j, ax in enumerate(la.axes):
                 if j0 is not None and j == j0:
                     idx.append(slice(None) if ctx.rows == ctx.bh else slice(0, ctx.rows))
@@ -335,21 +416,41 @@ def _tap(
                     )
                     tags.append(ax.pure_dim)
                 elif j == g.red_axis and g.resident:
-                    # whole operand resident in VMEM: index the global
-                    # reduction position (grid chunk * chunk + in-chunk rho)
+                    # whole operand resident in VMEM: read the global
+                    # reduction position (grid chunk * chunk + in-chunk
+                    # rho) through the ref, keeping a unit axis
                     rg = ctx.kg.red_grid
-                    idx.append(ctx.stepk * rg.chunk + ax.offset_at(rho) - g.base[j])
-                elif ax.pure_dim is not None:
-                    ep = ctx.extent(ax.pure_dim)
-                    start = ax.offset_at(rho) - g.base[j]
-                    idx.append(slice(start, start + ax.stride * (ep - 1) + 1, ax.stride))
+                    ref_idx[j] = pl.ds(
+                        ctx.stepk * rg.chunk + ax.offset_at(rho) - g.base[j], 1
+                    )
+                    idx.append(slice(None))
+                    tags.append(None)
+                elif j == last and seg is not None:
+                    # segmented view: this tap's elements are one segment,
+                    # read from its lane-aligned start
+                    stride, starts = seg
+                    k = starts.index(ax.offset_at(rho) - g.base[j])
+                    ref_idx[j] = pl.ds(
+                        k * _segment_width(g.span[j], stride),
+                        ctx.extent(ax.pure_dim),
+                    )
+                    idx.append(slice(None))
                     tags.append(ax.pure_dim)
+                elif ax.pure_dim is not None:
+                    span(j, ax.offset_at(rho) - g.base[j],
+                         ctx.extent(ax.pure_dim), ax.stride, ax.pure_dim)
                 else:
                     idx.append(ax.offset_at(rho) - g.base[j])
-    tap = block[tuple(idx)]
-    order = sorted(range(len(tags)), key=lambda t: ctx.pure_pos[tags[t]])
-    if order != list(range(len(tags))):
-        tap = jnp.transpose(tap, order)
+    tap = src[tuple(ref_idx)][tuple(idx)]
+    # order the kept pure-dim axes as the stage's dims, leaving unit axes
+    # in place, then reshape to the broadcastable block shape
+    pos = [t for t, d in enumerate(tags) if d is not None]
+    want = sorted(pos, key=lambda t: ctx.pure_pos[tags[t]])
+    if want != pos:
+        perm = list(range(len(tags)))
+        for p, w in zip(pos, want):
+            perm[p] = w
+        tap = jnp.transpose(tap, perm)
     newshape = tuple(
         ctx.block_shape[i] if d in tags else 1
         for i, d in enumerate(ctx.nstage.pure_dims)
@@ -489,6 +590,11 @@ class CompiledKernel:
     plan: KernelPlan                  # unified-buffer introspection
     _call: Callable
     mode: str = "interpret"
+    # the jitted program behind ``__call__``: takes one tuple of backing
+    # arrays, ordered as ``buffer_order``, so callers can ``.lower()`` it
+    # against shapes placed on a described (not attached) device
+    jitted: Optional[Callable] = None
+    buffer_order: Tuple[str, ...] = ()
 
     def __call__(self, buffers: Mapping[str, jax.Array]) -> jax.Array:
         return self._call(buffers)
@@ -753,7 +859,11 @@ class CompiledKernel:
 
 
 def emit_kernel(
-    kg: KernelGroup, *, interpret: bool = True, mode: Optional[str] = None
+    kg: KernelGroup,
+    *,
+    interpret: bool = True,
+    mode: Optional[str] = None,
+    vmem_budget: int = VMEM_BYTES,
 ) -> CompiledKernel:
     """Emit one executable ``pallas_call`` from a planned kernel group.
     All shape information (and its bounds validation) lives in the plan.
@@ -762,20 +872,21 @@ def emit_kernel(
     ``"compiled"`` | ``"auto"`` (see :func:`resolve_mode`).  The emitted
     closure is wrapped in ``jax.jit``, so repeated calls with same-shaped
     buffers reuse the first call's trace — binding new buffers to an
-    already-emitted kernel is cheap (the plan/emit/bind split)."""
+    already-emitted kernel is cheap (the plan/emit/bind split).
+
+    ``vmem_budget`` is the budget the plan was made under; a compiled
+    kernel is granted exactly that much VMEM (``vmem_limit_bytes``), so
+    the budget rule the verifier certifies (UB402) is the limit Mosaic
+    enforces, not the chip's smaller default scoped limit."""
     if mode is not None:
         mode = resolve_mode(mode)
         interpret = mode != "compiled"
     else:
         mode = "interpret" if interpret else "compiled"
-    if mode == "compiled" and jax.default_backend() != "tpu":
-        raise RuntimeError(
-            f"backend mode 'compiled' emits real (non-interpret) Mosaic "
-            f"kernels with TPU VMEM scratch and needs a TPU jax backend; "
-            f"default_backend() is {jax.default_backend()!r}.  Use "
-            f"mode='auto' to fall back to interpret mode off-TPU."
-        )
     ctxs = {sp.name: _StageCtx(kg, sp) for sp in kg.stages}
+    segments = {} if kg.lane_grid is not None else _lane_segments(kg)
+    for ctx in ctxs.values():
+        ctx.lane_segments = segments
     scratch_entries = kg.scratch_entries()
     n_groups = len(kg.groups)
     n_grid = len(kg.grid)
@@ -1023,9 +1134,16 @@ def emit_kernel(
             lambda b, *idx, f=index_map: (b,) + tuple(f(*idx)),
         )
 
+    def _block(gi: int, g: ViewGroup) -> Tuple[int, ...]:
+        blk = g.block_shape(kg.bh, kg.bw)
+        if gi in segments:
+            stride, starts = segments[gi]
+            blk = blk[:-1] + (len(starts) * _segment_width(blk[-1], stride),)
+        return blk
+
     in_specs = [
-        _batch_spec(g.block_shape(kg.bh, kg.bw), g.index_map(n_base, dim1))
-        for g in kg.groups
+        _batch_spec(_block(gi, g), g.index_map(n_base, dim1))
+        for gi, g in enumerate(kg.groups)
     ]
     out_nd = len(out_ctx.block_shape)
     if n_base == 1:
@@ -1040,6 +1158,10 @@ def emit_kernel(
         out_extents = (bg.steps,) + out_extents
     out_shape = jax.ShapeDtypeStruct(out_extents, jnp.float32)
     call_kwargs: Dict[str, object] = {}
+    if not interpret:
+        call_kwargs["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_budget
+        )
     if scratch_entries or kg.rings:
         call_kwargs["scratch_shapes"] = [
             pltpu.VMEM(sp.scratch_shape(kg.bh, key), jnp.float32)
@@ -1072,6 +1194,10 @@ def emit_kernel(
             ]
             for g in kg.groups
         ]
+        views = [
+            _segmented(v, *segments[gi]) if gi in segments else v
+            for gi, v in enumerate(views)
+        ]
         return pl.pallas_call(
             kernel,
             grid=kg.grid,
@@ -1083,6 +1209,15 @@ def emit_kernel(
         )(*views)
 
     def call(buffers: Mapping[str, jax.Array]) -> jax.Array:
+        # emission and lowering work anywhere (a compiled kernel can be
+        # lowered for a described TPU); only execution needs the chip
+        if not interpret and jax.default_backend() != "tpu":
+            raise RuntimeError(
+                f"kernel {out_sp.name!r}: backend mode 'compiled' runs real "
+                f"Mosaic kernels and needs a TPU jax backend; "
+                f"default_backend() is {jax.default_backend()!r}.  Use "
+                f"mode='auto' to fall back to interpret mode off-TPU."
+            )
         kg.validate_buffers(buffers)
         return _invoke(tuple(buffers[b] for b in buffer_order))
 
@@ -1093,6 +1228,8 @@ def emit_kernel(
         plan=kg.ub_plan(),
         _call=call,
         mode=mode,
+        jitted=_invoke,
+        buffer_order=tuple(buffer_order),
     )
 
 
@@ -1132,7 +1269,9 @@ def compile_stage(
         line_buffer=line_buffer,
         red_resident=red_resident,
     )
-    return emit_kernel(kg, interpret=interpret, mode=mode)
+    return emit_kernel(
+        kg, interpret=interpret, mode=mode, vmem_budget=vmem_budget
+    )
 
 
 # pre-refactor name: a single-stage CompiledKernel is the old CompiledStage

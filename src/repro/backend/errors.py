@@ -106,9 +106,8 @@ class PlanError(BackendError):
 
 class EmitError(BackendError, RuntimeError):
     """A certified plan failed to lower to an executable kernel.
-    Subclasses ``RuntimeError`` so the pre-taxonomy emission-gate
-    contract (compiled mode off-TPU raises a ``RuntimeError`` naming the
-    backend) keeps holding through the wrap."""
+    Subclasses ``RuntimeError`` for back-compat with callers that caught
+    the pre-taxonomy emission failures as ``RuntimeError``."""
 
     code = "EMIT"
 

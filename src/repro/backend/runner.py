@@ -27,9 +27,11 @@ differential tests can compare bit-for-bit element-wise.
 from __future__ import annotations
 
 import hashlib
+import os
 import warnings
 from collections import OrderedDict
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Dict, List, Mapping, Optional
 
 import jax
@@ -318,6 +320,23 @@ def pipeline_cache_stats() -> Dict[str, int]:
     return {**_CACHE_STATS, "entries": len(_PIPELINE_CACHE)}
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    no other path is set here.  Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache``, so the next run in the same checkout finds
+    what this one compiled.  Every compile is cached, however short: a
+    served pipeline compiles one kernel per plan group, most in about a
+    second.  Call it before the first compile."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
 def compile_pipeline(
     pipe: Pipeline,
     *,
@@ -354,6 +373,7 @@ def compile_pipeline(
 
     ``mode`` is the execution switch (``"interpret"`` | ``"compiled"`` |
     ``"auto"``); the legacy ``interpret`` boolean, when given, overrides it.
+    A compiled pipeline always plans with ``align_tpu``.
     ``cache=True`` consults the plan-keyed pipeline cache: a hit returns
     the previously compiled :class:`PallasPipeline` (its jit-warmed kernels
     included) without re-planning or re-emitting.
@@ -395,6 +415,9 @@ def compile_pipeline(
     if interpret is not None:
         mode = "interpret" if interpret else "compiled"
     mode = resolve_mode(mode)
+    # Mosaic refuses blocks whose last two dims are not (8, 128)-tileable,
+    # so a compiled pipeline always plans aligned tiles
+    align_tpu = align_tpu or mode == "compiled"
     plan_kwargs = dict(
         block_h=block_h,
         block_w=block_w,
@@ -454,7 +477,9 @@ def compile_pipeline(
     kernels = []
     for kg in plan.kernels:
         try:
-            kernels.append(emit_kernel(kg, mode=mode))
+            kernels.append(emit_kernel(
+                kg, mode=mode, vmem_budget=plan.notes["vmem_budget"]
+            ))
         except Exception as e:
             # a certified plan failing to lower is an emitter (or Pallas)
             # defect, not a caller error: name the kernel group instead of
@@ -531,6 +556,7 @@ __all__ = [
     "PallasPipeline",
     "TunedModeMismatchWarning",
     "compile_pipeline",
+    "enable_compile_cache",
     "plan_cache_key",
     "schedule_db_key",
     "TUNABLE_KEYS",

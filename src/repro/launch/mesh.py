@@ -7,11 +7,8 @@ outer data-parallel axis by default (optionally a pipeline axis, see
 distributed/pipeline.py).
 
 All mesh construction in this repo goes through :func:`make_mesh` /
-:func:`make_abstract_mesh` / :func:`mesh_context`: ``jax.sharding.AxisType``
-and ``jax.set_mesh`` only exist in newer jax releases, and passing
-``axis_types`` to ``jax.make_mesh`` crashes on jax 0.4.x.  These helpers use
-the new API surface when present and degrade gracefully otherwise, so the
-same call sites run on every supported jax.
+:func:`make_abstract_mesh` / :func:`mesh_context`, which fix the axis types
+(``AxisType.Auto``) in one place.
 """
 
 from __future__ import annotations
@@ -19,14 +16,7 @@ from __future__ import annotations
 from typing import ContextManager, Sequence
 
 import jax
-
-
-def _auto_axis_types(n: int):
-    """``(AxisType.Auto,) * n`` when the installed jax has AxisType, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n
+from jax.sharding import AxisType
 
 
 def make_mesh(
@@ -35,37 +25,27 @@ def make_mesh(
     *,
     devices=None,
 ) -> jax.sharding.Mesh:
-    """Version-compatible ``jax.make_mesh`` (``axis_types`` only when available)."""
-    kwargs = {}
-    types = _auto_axis_types(len(axis_names))
-    if types is not None:
-        kwargs["axis_types"] = types
-    if devices is not None:
-        kwargs["devices"] = devices
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(axis_names), **kwargs,
+    )
 
 
 def make_abstract_mesh(
     axis_shapes: Sequence[int], axis_names: Sequence[str]
 ) -> "jax.sharding.AbstractMesh":
-    """Abstract (device-free) mesh for sharding-spec math, on any jax.
-
-    New jax takes ``(axis_sizes, axis_names, axis_types=...)``; jax 0.4.x
-    takes a single ``((name, size), ...)`` shape tuple.
-    """
-    shapes, names = tuple(axis_shapes), tuple(axis_names)
-    types = _auto_axis_types(len(names))
-    if types is not None:
-        return jax.sharding.AbstractMesh(shapes, names, axis_types=types)
-    return jax.sharding.AbstractMesh(tuple(zip(names, shapes)))
+    """Abstract (device-free) mesh for sharding-spec math."""
+    return jax.sharding.AbstractMesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(axis_names),
+    )
 
 
 def mesh_context(mesh: jax.sharding.Mesh) -> ContextManager:
-    """``jax.set_mesh(mesh)`` when available, else the legacy Mesh context
-    manager (on jax 0.4.x entering the Mesh itself installs it)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Install ``mesh`` as the current mesh (``jax.set_mesh``)."""
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

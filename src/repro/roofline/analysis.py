@@ -135,12 +135,8 @@ class RooflineReport:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns a per-device *list* of dicts on
-    jax 0.4.x and a plain dict on newer jax; normalize to a dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``Compiled.cost_analysis()`` as a dict (empty when XLA gives none)."""
+    return compiled.cost_analysis() or {}
 
 
 def analyze_compiled(

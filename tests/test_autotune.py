@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -181,24 +182,32 @@ def test_interpret_measured_winner_warns_into_compiled_mode(tmp_path):
     from repro.backend.runner import TunedModeMismatchWarning
 
     app = make_app("gaussian", size=18)
-    key = schedule_db_key(app.pipeline, {})
+    # compiled mode always plans TPU-aligned tiles, so the interpret run
+    # that can stand in for it is an align_tpu=True one: same db key
+    aligned = {"align_tpu": True}
+    key = schedule_db_key(app.pipeline, aligned)
     db = ScheduleDB(path=str(tmp_path / "db.json"))
     db.store(key, {
-        "app": "gaussian", "schedule": {"block_h": 2}, "mode": "interpret",
+        "app": "gaussian", "schedule": {"block_h": 8}, "mode": "interpret",
     })
-    assert lookup_schedule_entry(app.pipeline, {}, db=db)["mode"] == "interpret"
+    entry = lookup_schedule_entry(app.pipeline, aligned, db=db)
+    assert entry["mode"] == "interpret"
 
     # same mode: silent (errors would surface as test failures)
     with warnings.catch_warnings():
         warnings.simplefilter("error", TunedModeMismatchWarning)
-        pp = compile_pipeline(app.pipeline, tune=db)
-    assert pp.kernels[0].bh == 2               # the schedule still applies
+        pp = compile_pipeline(app.pipeline, tune=db, align_tpu=True)
+    assert pp.kernels[0].bh == 8               # the schedule still applies
 
-    # mode="compiled": the warning fires at serve time, before emission
-    # (which then refuses off-TPU — the pre-existing compiled-mode gate)
+    # mode="compiled": the warning fires when the schedule is looked up;
+    # running the pipeline off-TPU is then refused by the compiled-mode gate
     with pytest.warns(TunedModeMismatchWarning, match="'interpret'.*'compiled'"):
+        pp = compile_pipeline(app.pipeline, mode="compiled", tune=db)
+    assert pp.kernels[0].bh == 8
+    if jax.default_backend() != "tpu":
+        inputs = {"input": np.zeros((18, 18), np.float32)}
         with pytest.raises(RuntimeError, match="TPU"):
-            compile_pipeline(app.pipeline, mode="compiled", tune=db)
+            pp(inputs)
 
 
 def test_tuned_numerics_match_heuristic(tmp_path):

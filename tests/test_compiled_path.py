@@ -76,12 +76,41 @@ def test_auto_mode_falls_back_cleanly():
 
 @pytest.mark.skipif(ON_TPU, reason="explicit compiled mode is legal here")
 def test_compiled_mode_on_cpu_raises_clearly():
+    """A compiled pipeline plans and emits anywhere (so its kernels can be
+    lowered for a described TPU), but running it off-TPU raises a named
+    error instead of silently falling back to the interpreter."""
     app = make_app("gaussian", size=18)
-    with pytest.raises(RuntimeError, match="TPU jax backend"):
-        compile_pipeline(app.pipeline, mode="compiled")
+    inputs = _inputs(app)
     # the legacy boolean spells the same request
-    with pytest.raises(RuntimeError, match="TPU jax backend"):
-        compile_pipeline(app.pipeline, interpret=False)
+    for kw in ({"mode": "compiled"}, {"interpret": False}):
+        pp = compile_pipeline(app.pipeline, **kw)
+        assert pp.mode == "compiled"
+        assert all(ck.mode == "compiled" for ck in pp.kernels)
+        with pytest.raises(RuntimeError, match="TPU jax backend"):
+            pp(inputs)
+
+
+def test_compiled_mode_plans_tpu_aligned_tiles():
+    """Mosaic refuses blocks whose last two dims are not (8, 128)-tileable:
+    at 1080 output rows the unaligned planner picks 10-row blocks, while a
+    compiled pipeline always plans with align_tpu — and an explicit
+    align_tpu=True names the same plan, so it shares the cache entry."""
+    app = make_app("gaussian", size=1082, width=1922)
+    assert compile_pipeline(app.pipeline).kernels[0].bh % 8 != 0
+    clear_pipeline_cache(reset_stats=True)
+    try:
+        pp = compile_pipeline(app.pipeline, mode="compiled", cache=True)
+        assert all(ck.bh % 8 == 0 for ck in pp.kernels)
+        assert pp.plan.notes["align_tpu"] is True
+        again = compile_pipeline(
+            app.pipeline, mode="compiled", cache=True, align_tpu=True
+        )
+        assert again is pp
+        assert pp.cache_key == plan_cache_key(
+            app.pipeline, "compiled", {"align_tpu": True}
+        )
+    finally:
+        clear_pipeline_cache(reset_stats=True)
 
 
 @pytest.mark.skipif(not ON_TPU, reason="needs a TPU backend for compiled mode")
@@ -255,3 +284,48 @@ def test_cached_pipeline_warm_invocation_is_10x_faster():
         assert warm * 10 < cold, (cold, warm)
     finally:
         clear_pipeline_cache()
+
+
+# ---------------------------------------------------------------------------
+# Persistent compile cache location
+# ---------------------------------------------------------------------------
+
+
+def _cache_child(env_dir, compile_one: bool):
+    """Run enable_compile_cache() in a fresh CPU process; return the path
+    it reports and the one JAX is configured with."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.backend import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        + ("jax.jit(lambda x: x * 3)(jnp.ones(4)).block_until_ready()\n"
+           if compile_one else "")
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(root / "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, env=env,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr
+    return proc.stdout.split(), root
+
+
+def test_compile_cache_follows_env_then_checkout(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and receives the
+    compiled entries; otherwise the cache is the fixed <checkout>/.jax_cache
+    (never a temp, pid or timestamp path)."""
+    got, _root = _cache_child(tmp_path / "cc", compile_one=True)
+    assert got == [str(tmp_path / "cc")] * 2
+    assert any((tmp_path / "cc").iterdir())
+    got, root = _cache_child(None, compile_one=False)
+    assert got == [str(root / ".jax_cache")] * 2
