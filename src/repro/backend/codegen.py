@@ -31,6 +31,7 @@ TPU compiler (Mosaic) can lower.
 from __future__ import annotations
 
 import itertools
+import re
 from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Callable, Dict, Iterator, List, Mapping, Optional, Tuple
@@ -44,6 +45,7 @@ from repro.core.ubplan import LANE, KernelPlan, VMEM_BYTES
 from repro.frontend.expr import BinOp, Const, Expr, FuncRef, IterVal, Select
 from repro.frontend.lower import NormalizedStage
 
+from . import tracing
 from .access import UnsupportedAccessError, decompose_stage
 from .plan import (
     KernelGroup,
@@ -63,10 +65,8 @@ _RING = object()
 # eval counter behind the computed-exactly-once property tests.  Scopes are
 # opened with the ``eval_trace()`` context manager and nest (each scope gets
 # its own list, so parametrized/parallel tests cannot clobber each other's
-# counters); the module-global ``EVAL_TRACE`` remains as a backwards-compat
-# shim for legacy callers that assign a list directly.
-_EVAL_TRACE_STACK: List[List[Dict]] = []
-EVAL_TRACE: Optional[List[Dict]] = None
+# counters).
+_EVAL_SCOPES: List[List[Dict]] = []
 
 
 @contextmanager
@@ -79,22 +79,19 @@ def eval_trace() -> Iterator[List[Dict]]:
 
     Sites fire at jit-trace time, so re-running an already-warm pipeline
     records nothing — arm the scope around the first invocation.  Scopes
-    nest: records go to the innermost active scope (plus the legacy
-    ``EVAL_TRACE`` shim when armed), so a helper tracing its own compile
-    does not pollute an enclosing test's counter."""
+    nest: records go to the innermost active scope, so a helper tracing its
+    own compile does not pollute an enclosing test's counter."""
     trace: List[Dict] = []
-    _EVAL_TRACE_STACK.append(trace)
+    _EVAL_SCOPES.append(trace)
     try:
         yield trace
     finally:
-        _EVAL_TRACE_STACK.remove(trace)
+        _EVAL_SCOPES.remove(trace)
 
 
 def _record_eval(record: Dict) -> None:
-    if _EVAL_TRACE_STACK:
-        _EVAL_TRACE_STACK[-1].append(record)
-    if EVAL_TRACE is not None:
-        EVAL_TRACE.append(record)
+    if _EVAL_SCOPES:
+        _EVAL_SCOPES[-1].append(record)
 
 
 # ---------------------------------------------------------------------------
@@ -531,7 +528,7 @@ def _stage_panel(
     ``lshift`` (in-kernel reductions unrolled).  ``when`` tags which grid
     steps execute this evaluation site ("every" or "step0") for the
     eval-trace instrumentation."""
-    if _EVAL_TRACE_STACK or EVAL_TRACE is not None:
+    if _EVAL_SCOPES:
         _record_eval({
             "kernel": ctx.kg.name,
             "stage": ctx.sp.name,
@@ -595,6 +592,9 @@ class CompiledKernel:
     # against shapes placed on a described (not attached) device
     jitted: Optional[Callable] = None
     buffer_order: Tuple[str, ...] = ()
+    # the host-side launch span (``tracing.KERNEL`` + kernel name), named
+    # once here so the serving path builds no string per call
+    span: str = ""
 
     def __call__(self, buffers: Mapping[str, jax.Array]) -> jax.Array:
         return self._call(buffers)
@@ -1186,7 +1186,11 @@ def emit_kernel(
     # slices apply past the untouched batch dim
     lead = (slice(None),) if bg is not None else ()
 
-    @jax.jit
+    # a stable name for the trace: the module reads ``jit_ub_<kernel>``
+    # and the Mosaic kernel ``ub_<kernel>``, whatever the code's fingerprint
+    safe = re.sub(r"[^A-Za-z0-9_]", "_", out_sp.name)
+    stable = "ub_" + safe
+
     def _invoke(arrays):
         views = [
             jnp.asarray(arrays[slot_of[g.buffer]], jnp.float32)[
@@ -1205,8 +1209,12 @@ def emit_kernel(
             out_specs=out_spec,
             out_shape=out_shape,
             interpret=interpret,
+            name=stable,
             **call_kwargs,
         )(*views)
+
+    _invoke.__name__ = _invoke.__qualname__ = stable
+    jitted = jax.jit(_invoke)
 
     def call(buffers: Mapping[str, jax.Array]) -> jax.Array:
         # emission and lowering work anywhere (a compiled kernel can be
@@ -1219,7 +1227,7 @@ def emit_kernel(
                 f"mode='auto' to fall back to interpret mode off-TPU."
             )
         kg.validate_buffers(buffers)
-        return _invoke(tuple(buffers[b] for b in buffer_order))
+        return jitted(tuple(buffers[b] for b in buffer_order))
 
     return CompiledKernel(
         name=out_sp.name,
@@ -1228,8 +1236,9 @@ def emit_kernel(
         plan=kg.ub_plan(),
         _call=call,
         mode=mode,
-        jitted=_invoke,
+        jitted=jitted,
         buffer_order=tuple(buffer_order),
+        span=tracing.KERNEL + safe,
     )
 
 

@@ -41,6 +41,7 @@ import numpy as np
 from repro.core.ubplan import VMEM_BYTES
 from repro.frontend.lower import Pipeline, execute_pipeline, normalize_pipeline
 
+from . import tracing
 from .codegen import CompiledKernel, emit_kernel, resolve_mode
 from .errors import (
     EmitError,
@@ -132,36 +133,38 @@ class PallasPipeline:
         batch = self.plan.notes.get("batch")
         cap = self.plan.notes.get("batch_capacity", batch)
         buffers: Dict[str, jax.Array] = {}
-        for name in self.pipeline.inputs:
-            if name not in inputs:
-                raise KeyError(
-                    f"missing input {name!r}; the plan requires "
-                    f"{sorted(self.pipeline.inputs)}"
-                )
-            arr = jnp.asarray(inputs[name], jnp.float32)
-            want = tuple(self.pipeline.buffer_boxes[name].extents)
-            if batch is not None:
-                want = (batch,) + want
-            if arr.ndim != len(want):
-                raise ValueError(
-                    f"input {name!r}: rank {arr.ndim} (shape "
-                    f"{tuple(arr.shape)}) != plan's declared rank "
-                    f"{len(want)} (extents {want}"
-                    + (f", leading dim = batch {batch})" if batch else ")")
-                )
-            if tuple(arr.shape) != want:
-                raise ValueError(
-                    f"input {name!r}: shape {tuple(arr.shape)} != the "
-                    f"plan's declared extents {want}"
-                    + (f" (leading dim = batch {batch})" if batch else "")
-                )
-            if batch is not None and cap > batch:
-                arr = jnp.concatenate(
-                    [arr, jnp.zeros((cap - batch,) + want[1:], jnp.float32)]
-                )
-            buffers[name] = arr
+        with tracing.span(tracing.TO_DEVICE):
+            for name in self.pipeline.inputs:
+                if name not in inputs:
+                    raise KeyError(
+                        f"missing input {name!r}; the plan requires "
+                        f"{sorted(self.pipeline.inputs)}"
+                    )
+                arr = jnp.asarray(inputs[name], jnp.float32)
+                want = tuple(self.pipeline.buffer_boxes[name].extents)
+                if batch is not None:
+                    want = (batch,) + want
+                if arr.ndim != len(want):
+                    raise ValueError(
+                        f"input {name!r}: rank {arr.ndim} (shape "
+                        f"{tuple(arr.shape)}) != plan's declared rank "
+                        f"{len(want)} (extents {want}"
+                        + (f", leading dim = batch {batch})" if batch else ")")
+                    )
+                if tuple(arr.shape) != want:
+                    raise ValueError(
+                        f"input {name!r}: shape {tuple(arr.shape)} != the "
+                        f"plan's declared extents {want}"
+                        + (f" (leading dim = batch {batch})" if batch else "")
+                    )
+                if batch is not None and cap > batch:
+                    arr = jnp.concatenate(
+                        [arr, jnp.zeros((cap - batch,) + want[1:], jnp.float32)]
+                    )
+                buffers[name] = arr
         for ck in self.kernels:
-            buffers[ck.name] = ck(buffers)
+            with tracing.span(ck.span):
+                buffers[ck.name] = ck(buffers)
         if batch is not None and cap > batch:
             buffers = {name: arr[:batch] for name, arr in buffers.items()}
         return buffers

@@ -85,6 +85,7 @@ import numpy as np
 from repro.frontend.lower import Pipeline
 from repro.serve.engine import pad_to_slots
 
+from . import tracing
 from .errors import (
     BackendError,
     DeadlineExceededError,
@@ -217,6 +218,9 @@ class PipelineServer:
         self.served = 0
         self.failed = 0
         self.dispatches = 0
+        # host<->device traffic of every dispatch, filler slots included
+        self.bytes_to_device = 0
+        self.bytes_from_device = 0
         self.fault_counters: Dict[str, int] = _fault_counter_zeros()
 
     # -- request lifecycle --------------------------------------------------
@@ -385,23 +389,28 @@ class PipelineServer:
         """One padded-to-capacity batched execution; returns per-kernel
         stacked host arrays.  Raises whatever the kernels raise — fault
         handling is the caller's (``_service``) job."""
-        slots = pad_to_slots(
-            reqs, self.batch_slots, lambda: self._zero_request(pipe)
-        )
-        ins = {
-            n: np.stack(
-                [np.asarray(r.inputs[n], np.float32) for r in slots]
+        with tracing.span(tracing.STACK):
+            slots = pad_to_slots(
+                reqs, self.batch_slots, lambda: self._zero_request(pipe)
             )
-            for n in pipe.inputs
-        }
+            ins = {
+                n: np.stack(
+                    [np.asarray(r.inputs[n], np.float32) for r in slots]
+                )
+                for n in pipe.inputs
+            }
+        self.bytes_to_device += sum(a.nbytes for a in ins.values())
         bufs = self._run_pipeline(pp, ins)
         self.dispatches += 1
         # one host conversion per kernel per dispatch — slicing per slot on
         # the jax array would pay a separate device sync per tile
-        return {
-            ck.name: np.asarray(bufs[ck.name])
-            for ck in pp.kernels
-        }
+        with tracing.span(tracing.COPY_BACK):
+            outs = {
+                ck.name: np.asarray(bufs[ck.name])
+                for ck in pp.kernels
+            }
+        self.bytes_from_device += sum(a.nbytes for a in outs.values())
+        return outs
 
     @staticmethod
     def _poisoned_slots(
@@ -410,11 +419,12 @@ class PipelineServer:
         """Live slot indices whose outputs contain NaN/Inf (filler slots
         run on zero inputs and are never read back)."""
         bad: List[int] = []
-        for b in range(n_live):
-            for arr in outs.values():
-                if not np.isfinite(arr[b]).all():
-                    bad.append(b)
-                    break
+        with tracing.span(tracing.FINITE_CHECK):
+            for b in range(n_live):
+                for arr in outs.values():
+                    if not np.isfinite(arr[b]).all():
+                        bad.append(b)
+                        break
         return bad
 
     def _complete(
@@ -445,12 +455,13 @@ class PipelineServer:
                 kw.pop(k, None)
             kw["tune"] = False
         self.fault_counters["recompiles"] += 1
-        fresh = compile_pipeline(
-            pipe,
-            batch=self.batch_slots,
-            batch_capacity=self.batch_slots,
-            **kw,
-        )
+        with tracing.span(tracing.RECOMPILE):
+            fresh = compile_pipeline(
+                pipe,
+                batch=self.batch_slots,
+                batch_capacity=self.batch_slots,
+                **kw,
+            )
         self._table[key] = (pipe, fresh, ckw)
         if pipe is self.pipe:
             self.pipeline = fresh
@@ -468,7 +479,8 @@ class PipelineServer:
         pipe, pp, _kw = self._table[key]
         self.fault_counters["quarantine_dispatches"] += 1
         try:
-            outs = self._dispatch(pipe, pp, reqs)
+            with tracing.span(tracing.QUARANTINE):
+                outs = self._dispatch(pipe, pp, reqs)
         except Exception as e:
             if len(reqs) == 1:
                 self.fault_counters["poisoned_tiles"] += 1
@@ -582,33 +594,34 @@ class PipelineServer:
         consecutive same-shape run at the head of the queue (up to
         ``batch_slots``), so mixed-shape traffic completes in submission
         order."""
-        now = self._clock()
-        finished: List[TileRequest] = list(self._expire(now))
-        if not self.pending:
+        with tracing.span(tracing.STEP, dispatch=self.dispatches):
+            now = self._clock()
+            finished: List[TileRequest] = list(self._expire(now))
+            if not self.pending:
+                return finished
+            key = self.pending[0][0]
+            reqs: List[TileRequest] = []
+            while (
+                self.pending
+                and len(reqs) < self.batch_slots
+                and self.pending[0][0] == key
+            ):
+                reqs.append(self.pending.popleft()[1])
+            self._service(key, reqs)
+            # completed-late check: a request whose deadline passed during the
+            # dispatch fails closed — its computed outputs are discarded, not
+            # returned late as if on time
+            end = self._clock()
+            for req in reqs:
+                if req.ok and req.deadline is not None and end > req.deadline:
+                    self.fault_counters["deadline_misses"] += 1
+                    self._fail(req, DeadlineExceededError(
+                        f"completed {end - req.deadline:.3f}s past the "
+                        f"deadline; late results are discarded",
+                    ))
+            self.served += len(reqs)
+            finished.extend(reqs)
             return finished
-        key = self.pending[0][0]
-        reqs: List[TileRequest] = []
-        while (
-            self.pending
-            and len(reqs) < self.batch_slots
-            and self.pending[0][0] == key
-        ):
-            reqs.append(self.pending.popleft()[1])
-        self._service(key, reqs)
-        # completed-late check: a request whose deadline passed during the
-        # dispatch fails closed — its computed outputs are discarded, not
-        # returned late as if on time
-        end = self._clock()
-        for req in reqs:
-            if req.ok and req.deadline is not None and end > req.deadline:
-                self.fault_counters["deadline_misses"] += 1
-                self._fail(req, DeadlineExceededError(
-                    f"completed {end - req.deadline:.3f}s past the "
-                    f"deadline; late results are discarded",
-                ))
-        self.served += len(reqs)
-        finished.extend(reqs)
-        return finished
 
     def run(
         self, requests: List[Union[TileRequest, Mapping[str, np.ndarray]]]
@@ -630,6 +643,8 @@ class PipelineServer:
             "served": self.served,
             "failed": self.failed,
             "dispatches": self.dispatches,
+            "bytes_to_device": self.bytes_to_device,
+            "bytes_from_device": self.bytes_from_device,
             "batch_slots": self.batch_slots,
             "shapes": len(self._table),
             "pending": len(self.pending),
