@@ -101,13 +101,18 @@ from .runner import (
     PallasPipeline,
     compile_pipeline,
     drop_pipeline_cache_entry,
+    pin_host_allocator,
     pipeline_cache_stats,
+    stage_dtype,
 )
 
-# dtypes a tile may arrive in: anything real-numeric casts losslessly
-# enough to the pipelines' f32 element type; everything else (object,
-# strings, complex, datetimes) would surface as a deep BlockSpec/Pallas
-# error at drain time and is rejected at submit instead
+# dtypes a tile may arrive in: anything real-numeric reaches the kernels'
+# float32 element type; everything else (object, strings, complex,
+# datetimes) would surface as a deep BlockSpec/Pallas error at drain time
+# and is rejected at submit instead.  A batch crosses to the device at its
+# live slots' common dtype where float32 holds it exactly (uint8, uint16,
+# int8, int16, bool, float16, float32) and is widened there; int32, int64
+# and float64 batches are cast to float32 on the host (runner.stage_dtype)
 _NUMERIC_KINDS = frozenset("fiub")
 
 
@@ -218,6 +223,8 @@ class PipelineServer:
         self.served = 0
         self.failed = 0
         self.dispatches = 0
+        # dispatches whose inputs crossed narrower than float32
+        self.narrow_dispatches = 0
         # host<->device traffic of every dispatch, filler slots included
         self.bytes_to_device = 0
         self.bytes_from_device = 0
@@ -256,10 +263,14 @@ class PipelineServer:
         return pp
 
     @staticmethod
-    def _zero_request(pipe: Pipeline) -> TileRequest:
+    def _zero_request(
+        pipe: Pipeline, dtypes: Mapping[str, np.dtype]
+    ) -> TileRequest:
+        """A filler tile at the batch's staged ``dtypes``: a float32
+        filler would promote a narrow batch back to float32."""
         return TileRequest(
             inputs={
-                n: np.zeros(PipelineServer._tile_shape(pipe, n), np.float32)
+                n: np.zeros(PipelineServer._tile_shape(pipe, n), dtypes[n])
                 for n in pipe.inputs
             },
             filler=True,
@@ -390,18 +401,28 @@ class PipelineServer:
         stacked host arrays.  Raises whatever the kernels raise — fault
         handling is the caller's (``_service``) job."""
         with tracing.span(tracing.STACK):
+            dtypes = {
+                n: stage_dtype([np.asarray(r.inputs[n]).dtype for r in reqs])
+                for n in pipe.inputs
+            }
             slots = pad_to_slots(
-                reqs, self.batch_slots, lambda: self._zero_request(pipe)
+                reqs, self.batch_slots,
+                lambda: self._zero_request(pipe, dtypes),
             )
+            # one pass into the staged stack: no per-frame float32 copy
             ins = {
                 n: np.stack(
-                    [np.asarray(r.inputs[n], np.float32) for r in slots]
+                    [np.asarray(r.inputs[n]) for r in slots],
+                    dtype=dtypes[n], casting="unsafe",
                 )
                 for n in pipe.inputs
             }
-        self.bytes_to_device += sum(a.nbytes for a in ins.values())
+        staged = sum(a.nbytes for a in ins.values())
+        self.bytes_to_device += staged
         bufs = self._run_pipeline(pp, ins)
         self.dispatches += 1
+        if any(a.dtype != np.float32 for a in ins.values()):
+            self.narrow_dispatches += 1
         # one host conversion per kernel per dispatch — slicing per slot on
         # the jax array would pay a separate device sync per tile
         with tracing.span(tracing.COPY_BACK):
@@ -409,7 +430,10 @@ class PipelineServer:
                 ck.name: np.asarray(bufs[ck.name])
                 for ck in pp.kernels
             }
-        self.bytes_from_device += sum(a.nbytes for a in outs.values())
+        copied = sum(a.nbytes for a in outs.values())
+        self.bytes_from_device += copied
+        # keep this dispatch's host buffers on the heap for the next one
+        pin_host_allocator(staged + copied)
         return outs
 
     @staticmethod
@@ -643,6 +667,7 @@ class PipelineServer:
             "served": self.served,
             "failed": self.failed,
             "dispatches": self.dispatches,
+            "narrow_dispatches": self.narrow_dispatches,
             "bytes_to_device": self.bytes_to_device,
             "bytes_from_device": self.bytes_from_device,
             "batch_slots": self.batch_slots,

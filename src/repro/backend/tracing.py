@@ -13,7 +13,7 @@ span = jax.profiler.TraceAnnotation
 
 PREFIX = "ub."
 STEP = "ub.step"                  # PipelineServer.step; argument dispatch=n
-STACK = "ub.stack"                # pad to slots + float32 np.stack
+STACK = "ub.stack"                # pad to slots + np.stack at the staged dtype
 TO_DEVICE = "ub.to_device"        # PallasPipeline.run: inputs onto the device
 KERNEL = "ub.kernel."             # + kernel name: one host-side launch
 COPY_BACK = "ub.copy_back"        # np.asarray of every kernel's output
