@@ -21,6 +21,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Tuple
 
+import numpy as np
+
 from repro.frontend.expr import Const, IterVal, Select, maximum, minimum
 from repro.frontend.func import Func, RDom, Var
 from repro.frontend.lower import Pipeline, lower_pipeline
@@ -304,10 +306,26 @@ def build_camera(size: int = 30) -> AppBundle:
 
 
 def build_resnet(
-    img: int = 16, cin: int = 8, cout: int = 8, tiles: int = 4
+    img: int = 16, cin: int = 8, cout: int = 8, tiles: int = 4,
+    weights=None,
 ) -> AppBundle:
+    """``weights`` (shape ``(cout, cin, 3, 3)``, loop order) binds the
+    weights as a parameter held by the compiled pipeline, so each request
+    carries only ``ifmap`` and the channel reduction runs as MXU
+    contractions; without it they arrive with every request."""
     inp = Func.input("ifmap", 3)     # indexed [x, y, ci]
-    wgt = Func.input("weights", 4)   # indexed [kx, ky, ci, co]
+    if weights is None:
+        wgt = Func.input("weights", 4)   # indexed [kx, ky, ci, co]
+        input_extents = {
+            "ifmap": (cin, img + 2, img + 2), "weights": (cout, cin, 3, 3)
+        }
+    else:
+        if np.shape(weights) != (cout, cin, 3, 3):
+            raise ValueError(
+                f"weights of shape {np.shape(weights)} != {(cout, cin, 3, 3)}"
+            )
+        wgt = Func.param("weights", weights)
+        input_extents = {"ifmap": (cin, img + 2, img + 2)}
     r = RDom(3, 3, cin, name="r")    # (kx, ky, ci) reduction
     rx, ry, rc = r[0], r[1], r[2]
 
@@ -328,9 +346,109 @@ def build_resnet(
     return AppBundle(
         "resnet", "dnn", pipe, funcs, conv,
         {"x": img, "y": img, "co": cout},
-        {"ifmap": (cin, img + 2, img + 2), "weights": (cout, cin, 3, 3)},
+        input_extents,
         tile_count=tiles,
         description="layer using multi-channel convolution",
+    )
+
+
+# ---------------------------------------------------------------------------
+# resnet50_block — ResNet-50 v1.5 identity bottleneck block (conv2_x)
+# ---------------------------------------------------------------------------
+
+
+def resnet50_block_weights(cin: int, mid: int, seed: int):
+    """The block's folded weights and biases, drawn in this order from
+    ``numpy.random.default_rng(seed)``: He-normal ``w1 (cin, mid)``, bias
+    ``b1 (mid,)``, ``w2 (3, 3, mid, mid)`` (indexed ky, kx, in, out),
+    ``b2 (mid,)``, ``w3 (mid, cin)``, ``b3 (cin,)``; biases are normal
+    draws of standard deviation 0.1.  Each is rounded to float32."""
+    rng = np.random.default_rng(seed)
+
+    def he(shape, fan_in):
+        return rng.standard_normal(shape) * np.sqrt(2.0 / fan_in)
+
+    def bias(n):
+        return 0.1 * rng.standard_normal(n)
+
+    w1 = he((cin, mid), cin)
+    b1 = bias(mid)
+    w2 = he((3, 3, mid, mid), 9 * mid)
+    b2 = bias(mid)
+    w3 = he((mid, cin), mid)
+    b3 = bias(cin)
+    return tuple(a.astype(np.float32) for a in (w1, b1, w2, b2, w3, b3))
+
+
+def build_resnet50_block(
+    img: int = 56, cin: int = 256, mid: int = 64, weight_seed: int = 0,
+    act_scale: float = 1 / 64,
+) -> AppBundle:
+    """The identity bottleneck block of ResNet-50 v1.5 (He et al. 2015,
+    Table 1, conv2_x) with batch norm folded into weights and biases:
+
+        h1 = relu(conv1x1(x; cin -> mid) + b1)
+        h2 = relu(conv3x3(h1; mid -> mid, zero padding 1) + b2)
+        y  = relu(conv1x1(h2; mid -> cin) + b3 + x)
+
+    The request ``ifmap`` holds uint8 activation codes ``(cin, img + 2,
+    img + 2)`` with a one-pixel ring, ``x = act_scale * code``; the ring is
+    read only where ``h1`` is zero, its padding.  The weights
+    (:func:`resnet50_block_weights`) are parameters, so every channel
+    reduction is an MXU contraction; ``act_scale`` is folded into ``w1``
+    (exact for a power of two).  The output is ``(cin, img, img)``."""
+    w1, b1, w2, b2, w3, b3 = resnet50_block_weights(cin, mid, weight_seed)
+    m, n = Var("m"), Var("n")
+    inp = Func.input("ifmap", 3)                 # [x, y, c]
+    p_w1 = Func.param("w1", w1 * np.float32(act_scale))   # [m, c]
+    p_b1 = Func.param("b1", b1)                  # [m]
+    p_w2 = Func.param("w2", w2)                  # [n, m, kx, ky]
+    p_b2 = Func.param("b2", b2)
+    p_w3 = Func.param("w3", w3)                  # [co, n]
+    p_b3 = Func.param("b3", b3)
+
+    rc = RDom(cin, name="c")
+    c1 = Func("conv1")                           # (y, x, m): channels last
+    c1[m, x, y] = 0
+    c1.update((m, x, y), c1[m, x, y] + inp[x, y, rc[0]] * p_w1[m, rc[0]], rc)
+    inside = (
+        (IterVal("x") > 0) * (IterVal("x") < img + 1)
+        * (IterVal("y") > 0) * (IterVal("y") < img + 1)
+    )
+    h1 = Func("h1")                              # zero on its one-pixel ring
+    h1[m, x, y] = Select(inside, maximum(c1[m, x, y] + p_b1[m], 0), Const(0))
+
+    r = RDom(3, 3, mid, name="k")                # (kx, ky, m)
+    c2 = Func("conv2")
+    c2[n, x, y] = 0
+    c2.update(
+        (n, x, y),
+        c2[n, x, y] + h1[r[2], x + r[0], y + r[1]] * p_w2[n, r[2], r[0], r[1]],
+        r,
+    )
+    h2 = Func("h2")
+    h2[n, x, y] = maximum(c2[n, x, y] + p_b2[n], 0)
+
+    rm = RDom(mid, name="q")
+    c3 = Func("conv3")                           # (co, y, x): channels first
+    c3[x, y, co] = 0
+    c3.update((x, y, co), c3[x, y, co] + h2[rm[0], x, y] * p_w3[co, rm[0]], rm)
+    out = Func("resnet50_block")
+    out[x, y, co] = maximum(
+        c3[x, y, co] + p_b3[co] + inp[x + 1, y + 1, co] * act_scale, 0
+    )
+
+    for f in (c1, h1, c2, h2, c3):
+        f.store_root()
+    out.hw_accelerate()
+    funcs = [inp, p_w1, p_b1, p_w2, p_b2, p_w3, p_b3, c1, h1, c2, h2, c3, out]
+    pipe = lower_pipeline(out, funcs, {"x": img, "y": img, "co": cin})
+    return AppBundle(
+        "resnet50_block", "dnn", pipe, funcs, out,
+        {"x": img, "y": img, "co": cin},
+        {"ifmap": (cin, img + 2, img + 2)},
+        description="ResNet-50 v1.5 identity bottleneck block, weights on "
+                    "the device",
     )
 
 
@@ -417,7 +535,7 @@ def build_matmul(m: int = 32, n: int = 32, k: int = 32) -> AppBundle:
 # ---------------------------------------------------------------------------
 ALL_APPS = ["gaussian", "harris", "upsample", "unsharp", "camera", "resnet", "mobilenet"]
 # additional backend workloads, not part of the paper's Table III set
-EXTRA_APPS = ["matmul"]
+EXTRA_APPS = ["matmul", "resnet50_block"]
 
 
 def make_app(name: str, **kw) -> AppBundle:
@@ -430,10 +548,13 @@ def make_app(name: str, **kw) -> AppBundle:
         "resnet": build_resnet,
         "mobilenet": build_mobilenet,
         "matmul": build_matmul,
+        "resnet50_block": build_resnet50_block,
     }
     return builders[name](**kw)
 
 
-__all__ = ["AppBundle", "ALL_APPS", "EXTRA_APPS", "make_app"] + [
+__all__ = [
+    "AppBundle", "ALL_APPS", "EXTRA_APPS", "make_app", "resnet50_block_weights",
+] + [
     f"build_{n}" for n in ALL_APPS + EXTRA_APPS
 ]

@@ -60,6 +60,10 @@ from .plan import (
 # with (stage name, shift) keys)
 _RING = object()
 
+# operand precision of a contraction's matrix products: float32 operands
+# (several bfloat16 MXU passes), accumulated in float32
+CONTRACT_PRECISION = jax.lax.Precision.HIGHEST
+
 # test instrumentation: every panel/warm-up evaluation site records
 # {kernel, stage, shift, rows, when} as the kernel function is traced — the
 # eval counter behind the computed-exactly-once property tests.  Scopes are
@@ -283,16 +287,47 @@ def _tap(
     shift: int,
     lshift: int = 0,
 ):
-    """Extract one load's value lattice — from a delivered view block, a
+    """Extract one load's value lattice (:func:`_tap_raw`) and align it
+    with the stage's output block (transpose + broadcast axes)."""
+    tap, tags = _tap_raw(ctx, refs, scratch, load_idx, rho, shift, lshift)
+    # order the kept pure-dim axes as the stage's dims, leaving unit axes
+    # in place, then reshape to the broadcastable block shape
+    pos = [t for t, d in enumerate(tags) if d is not None]
+    want = sorted(pos, key=lambda t: ctx.pure_pos[tags[t]])
+    if want != pos:
+        perm = list(range(len(tags)))
+        for p, w in zip(pos, want):
+            perm[p] = w
+        tap = jnp.transpose(tap, perm)
+    newshape = tuple(
+        ctx.block_shape[i] if d in tags else 1
+        for i, d in enumerate(ctx.nstage.pure_dims)
+    )
+    return tap.reshape(newshape)
+
+
+def _tap_raw(
+    ctx: _StageCtx,
+    refs,
+    scratch: Mapping[Tuple[str, int], object],
+    load_idx: int,
+    rho: Mapping[str, int],
+    shift: int,
+    lshift: int = 0,
+    keep: Optional[str] = None,
+) -> Tuple[jax.Array, List[Optional[str]]]:
+    """One load's value lattice — from a delivered view block, a
     cross-grid-step ring (input delivery or line-buffered intermediate), or
-    an in-kernel scratch panel — and align it with the stage's output block
-    (transpose + broadcast axes).
+    an in-kernel scratch panel — with ``tags`` naming the dim of each axis
+    it keeps, in the source's axis order.
 
     Each source axis gets a ref index (applied by the load) and a value
     index (applied to the loaded block): strided spans and the traced
     global reduction position of a resident operand are read through the
-    ref, every other offset slices the loaded value.  ``tags`` names the
-    pure dim of each axis the tap keeps (None for a kept unit axis)."""
+    ref, every other offset slices the loaded value.  A tag is the pure
+    dim of a kept axis, None for a kept unit axis, or ``keep``: the one
+    reduction dim whose axis is kept whole (a contraction's channel dim,
+    absent from ``rho``)."""
     sp = ctx.sp
     la = sp.accesses[load_idx]
     ref_idx: List[object] = [slice(None)] * len(la.axes)
@@ -304,7 +339,29 @@ def _tap(
         idx.append(v)
         tags.append(dim)
 
-    if sp.load_kind[load_idx] == "scratch":
+    def plain(j: int, ax, base: int, unit: bool = False) -> None:
+        """An axis no grid dim tiles: the kept reduction axis, a pure-dim
+        span, a kept unit axis (``unit``), or a squeezed static index."""
+        if keep is not None and keep in dict(ax.red_coeffs):
+            k0 = ax.const - base
+            idx.append(slice(k0, k0 + ctx.nstage.extent(keep)))
+            tags.append(keep)
+        elif ax.pure_dim is not None:
+            span(j, ax.offset_at(rho) - base, ctx.extent(ax.pure_dim),
+                 ax.stride, ax.pure_dim)
+        elif unit:
+            idx.append(slice(None))
+            tags.append(None)
+        else:
+            idx.append(ax.offset_at(rho) - base)
+
+    if sp.load_kind[load_idx] == "scratch" and not ctx.streamed:
+        # a whole kernel holds each producer as one whole panel: every
+        # axis is addressed from zero, as a view's untiled axes are
+        src = scratch[(sp.scratch_producer[load_idx], 0)]
+        for j, ax in enumerate(la.axes):
+            plain(j, ax, 0)
+    elif sp.load_kind[load_idx] == "scratch":
         pname = sp.scratch_producer[load_idx]
         slot = la.axes[0].offset_at(rho) + shift
         plb = ctx.kg.stage_plan(pname).line_buffer
@@ -433,26 +490,33 @@ def _tap(
                     )
                     idx.append(slice(None))
                     tags.append(ax.pure_dim)
-                elif ax.pure_dim is not None:
-                    span(j, ax.offset_at(rho) - g.base[j],
-                         ctx.extent(ax.pure_dim), ax.stride, ax.pure_dim)
                 else:
-                    idx.append(ax.offset_at(rho) - g.base[j])
-    tap = src[tuple(ref_idx)][tuple(idx)]
-    # order the kept pure-dim axes as the stage's dims, leaving unit axes
-    # in place, then reshape to the broadcastable block shape
-    pos = [t for t, d in enumerate(tags) if d is not None]
-    want = sorted(pos, key=lambda t: ctx.pure_pos[tags[t]])
-    if want != pos:
-        perm = list(range(len(tags)))
-        for p, w in zip(pos, want):
-            perm[p] = w
-        tap = jnp.transpose(tap, perm)
-    newshape = tuple(
-        ctx.block_shape[i] if d in tags else 1
-        for i, d in enumerate(ctx.nstage.pure_dims)
+                    # a parameter keeps its unit axes: its layout already
+                    # puts every axis where the stage's block has it
+                    plain(j, ax, g.base[j], unit=g.param and g.span[j] == 1)
+    return src[tuple(ref_idx)][tuple(idx)], tags
+
+
+def _contract(
+    ctx: _StageCtx, refs, scratch, rho: Mapping[str, int], shift: int,
+    lshift: int,
+):
+    """One tap of a :class:`~repro.backend.plan.Contraction`: the
+    activation's lattice with its channel axis kept, times the
+    parameter's ``(K, N)`` matrix at the tap, as one MXU matrix product in
+    float32 at float32 operand precision, ordered as the stage's block."""
+    cn = ctx.sp.contraction
+    a, at = _tap_raw(ctx, refs, scratch, cn.lhs, rho, shift, lshift, cn.dim)
+    w, wt = _tap_raw(ctx, refs, scratch, cn.rhs, rho, shift, lshift, cn.dim)
+    prod = jax.lax.dot_general(
+        a, w, (((at.index(cn.dim),), (wt.index(cn.dim),)), ((), ())),
+        precision=CONTRACT_PRECISION, preferred_element_type=jnp.float32,
     )
-    return tap.reshape(newshape)
+    tags = [t for t in at if t != cn.dim] + [t for t in wt if t != cn.dim]
+    perm = sorted(range(len(tags)), key=lambda t: ctx.pure_pos[tags[t]])
+    if perm != list(range(len(tags))):
+        prod = jnp.transpose(prod, perm)
+    return prod.reshape(ctx.block_shape)
 
 
 def _emit(
@@ -538,7 +602,17 @@ def _stage_panel(
             "when": when,
         })
     ns = ctx.nstage
-    if ns.red_dims:
+    cn = ctx.sp.contraction
+    if cn is not None:
+        # one matrix product per spatial tap, the channels on the MXU
+        acc = _emit(ns.init, ctx, refs, scratch, {}, shift, [0], lshift)
+        acc = jnp.broadcast_to(
+            jnp.asarray(acc, jnp.float32), ctx.block_shape
+        ).astype(jnp.float32)
+        for combo in itertools.product(*(range(ns.extent(d)) for d in cn.taps)):
+            rho = dict(zip(cn.taps, combo))
+            acc = acc + _contract(ctx, refs, scratch, rho, shift, lshift)
+    elif ns.red_dims:
         acc = _emit(ns.init, ctx, refs, scratch, {}, shift, [0], lshift)
         acc = jnp.broadcast_to(
             jnp.asarray(acc, jnp.float32), ctx.block_shape
@@ -858,12 +932,36 @@ class CompiledKernel:
         return g.base[axis_j], g.base[axis_j] + g.span[axis_j] - 1, 1
 
 
+class _BoundParams:
+    """A jitted kernel with its parameters bound: called, or lowered, with
+    the per-request buffers alone, as a kernel without parameters is.  The
+    parameters are the device arrays the pipeline uploaded once; lowering
+    describes them on the per-request buffers' placement, so
+    ``lower(buffers)`` lowers the executable that calls run."""
+
+    def __init__(self, jitted, params: Tuple[jax.Array, ...]):
+        self.jitted = jitted
+        self.params = params
+
+    def __call__(self, arrays):
+        return self.jitted(arrays, self.params)
+
+    def lower(self, arrays):
+        where = getattr(arrays[0], "sharding", None) if arrays else None
+        params = tuple(
+            jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=where)
+            for p in self.params
+        )
+        return self.jitted.lower(arrays, params)
+
+
 def emit_kernel(
     kg: KernelGroup,
     *,
     interpret: bool = True,
     mode: Optional[str] = None,
     vmem_budget: int = VMEM_BYTES,
+    params: Optional[Mapping[str, jax.Array]] = None,
 ) -> CompiledKernel:
     """Emit one executable ``pallas_call`` from a planned kernel group.
     All shape information (and its bounds validation) lives in the plan.
@@ -877,7 +975,11 @@ def emit_kernel(
     ``vmem_budget`` is the budget the plan was made under; a compiled
     kernel is granted exactly that much VMEM (``vmem_limit_bytes``), so
     the budget rule the verifier certifies (UB402) is the limit Mosaic
-    enforces, not the chip's smaller default scoped limit."""
+    enforces, not the chip's smaller default scoped limit.
+
+    ``params`` maps each parameter the kernel reads to its device array
+    (in the plan's layout); the jitted program binds them, so it is called
+    and lowered with the per-request buffers of ``buffer_order`` alone."""
     if mode is not None:
         mode = resolve_mode(mode)
         interpret = mode != "compiled"
@@ -1120,8 +1222,6 @@ def emit_kernel(
             ctx.stepk = 0
             ctx.stepj = 0
 
-    dim1 = "lane" if lane else "red"
-
     # under a batch grid every spec gains a leading size-None batch block:
     # Pallas squeezes the unit batch dim away, so the kernel body sees
     # refs shaped exactly as in the unbatched plan — the whole batched
@@ -1141,8 +1241,13 @@ def emit_kernel(
             blk = blk[:-1] + (len(starts) * _segment_width(blk[-1], stride),)
         return blk
 
+    # a parameter has no batch dim: the kernel sees its whole block
     in_specs = [
-        _batch_spec(_block(gi, g), g.index_map(n_base, dim1))
+        pl.BlockSpec(
+            ((None,) if bg is not None and not g.param else ())
+            + tuple(_block(gi, g)),
+            kg.view_index_map(gi),
+        )
         for gi, g in enumerate(kg.groups)
     ]
     out_nd = len(out_ctx.block_shape)
@@ -1177,10 +1282,19 @@ def emit_kernel(
     # backing arrays positionally and carves every planned view inside the
     # trace, so re-binding new buffers hits the jit cache (no re-trace)
     buffer_order: List[str] = []
+    param_order: List[str] = []
     for g in kg.groups:
-        if g.buffer not in buffer_order:
-            buffer_order.append(g.buffer)
+        order = param_order if g.param else buffer_order
+        if g.buffer not in order:
+            order.append(g.buffer)
     slot_of = {b: i for i, b in enumerate(buffer_order)}
+    param_slot = {b: i for i, b in enumerate(param_order)}
+    missing = set(param_order) - set(params or {})
+    if missing:
+        raise ValueError(
+            f"kernel {out_sp.name!r}: no device array for parameter(s) "
+            f"{sorted(missing)}"
+        )
 
     # batched arrays are stacked (capacity, *buffer); the per-tile view
     # slices apply past the untouched batch dim
@@ -1191,9 +1305,10 @@ def emit_kernel(
     safe = re.sub(r"[^A-Za-z0-9_]", "_", out_sp.name)
     stable = "ub_" + safe
 
-    def _invoke(arrays):
+    def _invoke(arrays, bound=()):
         views = [
-            jnp.asarray(arrays[slot_of[g.buffer]], jnp.float32)[
+            bound[param_slot[g.buffer]] if g.param
+            else jnp.asarray(arrays[slot_of[g.buffer]], jnp.float32)[
                 lead + g.view_slices(e0, e1)
             ]
             for g in kg.groups
@@ -1215,6 +1330,8 @@ def emit_kernel(
 
     _invoke.__name__ = _invoke.__qualname__ = stable
     jitted = jax.jit(_invoke)
+    if param_order:
+        jitted = _BoundParams(jitted, tuple(params[b] for b in param_order))
 
     def call(buffers: Mapping[str, jax.Array]) -> jax.Array:
         # emission and lowering work anywhere (a compiled kernel can be

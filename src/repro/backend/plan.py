@@ -65,6 +65,8 @@ import math
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Set, Tuple
 
+import numpy as np
+
 from repro.core.scheduling import raster_cycles
 from repro.core.ubplan import (
     KernelPlan,
@@ -76,10 +78,10 @@ from repro.core.ubplan import (
     lane_width_candidates,
     plan_affine_stage,
 )
-from repro.frontend.expr import expr_depth, refs_in
+from repro.frontend.expr import BinOp, FuncRef, expr_depth, refs_in
 from repro.frontend.lower import NormalizedStage, Pipeline, normalize_pipeline
 
-from .access import LoadAccess, UnsupportedAccessError, decompose_stage
+from .access import AxisAccess, LoadAccess, UnsupportedAccessError, decompose_stage
 from .errors import PlanError
 
 ELEM_BYTES = 4                      # all generated streams are f32
@@ -284,6 +286,8 @@ class ViewGroup:
                                       # fixed ``cols0``-column block delivered
                                       # once per row step (lane index pinned 0)
     cols0: int = 0                    # lane-axis block columns when lane_pinned
+    param: bool = False               # a parameter, held whole: no batch dim,
+                                      # one block for every grid step
 
     def view_slices(self, e0: int, e1: Optional[int] = None) -> Tuple[slice, ...]:
         out = []
@@ -386,6 +390,8 @@ class StagePlan:
     lane_shifts: Tuple[int, ...] = (0,)
     bw: Optional[int] = None
     lane_axis_of: List[Optional[int]] = field(default_factory=list)
+    # a reduction emitted as matrix products on the MXU (see Contraction)
+    contraction: Optional["Contraction"] = None
 
     @property
     def name(self) -> str:
@@ -507,6 +513,150 @@ class RedGrid:
         return self.extent - (self.steps - 1) * self.chunk
 
 
+@dataclass(frozen=True)
+class Contraction:
+    """A channel reduction ``sum_k A[..., k] * W[k, n]`` emitted as one
+    matrix product per combination of the other reduction dims (the
+    spatial taps) instead of unrolled outer products.
+
+    ``W`` (load ``rhs``) is a parameter read only by reduction dims and by
+    one pure dim ``out_dim`` (the output channel) that ``A`` (load
+    ``lhs``) does not read; ``A`` reads the contracted dim ``dim`` alone on
+    its first or last kept axis, the two places Mosaic's matmul contracts.
+    The parameter's layout (:class:`ParamLayout`) puts the tap axes first
+    and ``(dim, out_dim)`` last, so each tap's weights are one
+    ``(K, N)`` matrix with N on lanes."""
+
+    lhs: int                          # load index of the activation A
+    rhs: int                          # load index of the parameter W
+    dim: str                          # contracted reduction dim (channels)
+    out_dim: str                      # pure dim W contributes (out channels)
+    taps: Tuple[str, ...]             # the other reduction dims, outermost first
+
+
+@dataclass(frozen=True)
+class ParamLayout:
+    """How a parameter is laid out on the device.  ``axes[j]`` is the
+    source axis that device axis ``j`` holds, or None for an inserted unit
+    axis; the device array is the bound array transposed and reshaped to
+    ``shape``.  Decided once per pipeline (:func:`_param_layouts`)."""
+
+    axes: Tuple[Optional[int], ...]
+    shape: Tuple[int, ...]
+
+    def apply(self, value) -> np.ndarray:
+        perm = [a for a in self.axes if a is not None]
+        return np.ascontiguousarray(
+            np.transpose(np.asarray(value, np.float32), perm).reshape(self.shape)
+        )
+
+    def access(self, la: LoadAccess) -> LoadAccess:
+        """``la`` (a load of the parameter in its source layout) as a load
+        of the device layout."""
+        unit = AxisAccess(None, 1, (), 0)
+        return LoadAccess(la.buffer, tuple(
+            unit if a is None else la.axes[a] for a in self.axes
+        ))
+
+
+def _whole_axis(ax: AxisAccess) -> Optional[Tuple[str, bool]]:
+    """``(dim, is_pure)`` when the axis is read by one dim alone at
+    coefficient 1 and offset 0 (so it is read whole), else None."""
+    if ax.const != 0:
+        return None
+    if ax.pure_dim is not None:
+        return (ax.pure_dim, True) if not ax.red_coeffs and ax.stride == 1 else None
+    if len(ax.red_coeffs) == 1 and ax.red_coeffs[0][1] == 1:
+        return ax.red_coeffs[0][0], False
+    return None
+
+
+def _contraction(
+    ns: NormalizedStage, accesses: Sequence[LoadAccess], params: Set[str]
+) -> Optional[Contraction]:
+    """The stage's reduction as a :class:`Contraction`, or None where it
+    is not one (then it keeps the unrolled path).  The term must be the
+    product of two loads, one of them a parameter."""
+    v = ns.value
+    if not (
+        ns.red_dims and isinstance(v, BinOp) and v.op == "mul"
+        and isinstance(v.a, FuncRef) and isinstance(v.b, FuncRef)
+        and len(accesses) == 2
+    ):
+        return None
+    roles = [k for k in (1, 0) if accesses[k].buffer in params]
+    if len(roles) != 1:
+        return None
+    rhs = roles[0]
+    lhs = 1 - rhs
+    w, a = accesses[rhs], accesses[lhs]
+    reads = [_whole_axis(ax) for ax in w.axes]
+    if None in reads:
+        return None
+    pure = [d for d, p in reads if p]
+    reds = [d for d, p in reads if not p]
+    if len(pure) != 1 or len(set(reds)) != len(reds) or set(reds) != set(ns.red_dims):
+        return None
+    out_dim = pure[0]
+    a_pure = {ax.pure_dim for ax in a.axes if ax.pure_dim is not None}
+    if out_dim in a_pure or a_pure | {out_dim} != set(ns.pure_dims):
+        return None
+    # the contracted dim: the one reduction dim A reads whole on one axis
+    alone = [
+        r[0] for ax in a.axes
+        for r in [_whole_axis(ax)] if r is not None and not r[1]
+    ]
+    if len(alone) != 1:
+        return None
+    dim = alone[0]
+    if any(dim in dict(ax.red_coeffs) and _whole_axis(ax) is None for ax in a.axes):
+        return None
+    kept = [
+        ax for ax in a.axes
+        if ax.pure_dim is not None or _whole_axis(ax) == (dim, False)
+    ]
+    if _whole_axis(kept[0]) != (dim, False) and _whole_axis(kept[-1]) != (dim, False):
+        return None
+    taps = tuple(r for r in ns.red_dims if r != dim)
+    return Contraction(lhs, rhs, dim, out_dim, taps)
+
+
+def _param_layouts(
+    infos: Sequence[Tuple[NormalizedStage, List[LoadAccess], bool]],
+    contractions: Mapping[str, Contraction],
+    params: Mapping[str, Tuple[int, ...]],
+) -> Dict[str, ParamLayout]:
+    """One device layout per parameter, from its first load: a
+    contraction's weights go tap axes first, then the contracted axis,
+    then the output-channel axis (one ``(K, N)`` matrix per tap); a
+    parameter read whole by pure dims alone (a per-channel bias) takes the
+    reading stage's rank, its axes in the stage's dim order and unit axes
+    elsewhere, so its tap needs no relayout in the kernel; any other keeps
+    its own layout."""
+    out: Dict[str, ParamLayout] = {}
+    for ns, acc, _ in infos:
+        cn = contractions.get(ns.name)
+        for k, la in enumerate(acc):
+            name = la.buffer
+            if name not in params or name in out:
+                continue
+            shape = params[name]
+            reads = [_whole_axis(ax) for ax in la.axes]
+            axes: Tuple[Optional[int], ...] = tuple(range(len(shape)))
+            if cn is not None and k == cn.rhs:
+                pos = {d: j for j, (d, _p) in enumerate(reads)}
+                axes = tuple(pos[d] for d in cn.taps) + (
+                    pos[cn.dim], pos[cn.out_dim]
+                )
+            elif None not in reads and all(p for _d, p in reads):
+                pos = {d: j for j, (d, _p) in enumerate(reads)}
+                axes = tuple(pos.get(d) for d in ns.pure_dims)
+            out[name] = ParamLayout(axes, tuple(
+                1 if a is None else shape[a] for a in axes
+            ))
+    return out
+
+
 # ---------------------------------------------------------------------------
 # Kernel groups
 # ---------------------------------------------------------------------------
@@ -621,11 +771,26 @@ class KernelGroup:
         """Grid extent along the lane dim (1 when not lane-blocked)."""
         return self.grid[self.bofs + 1] if self.lane_grid is not None else 1
 
+    def view_index_map(self, gi: int) -> Callable:
+        """The BlockSpec index map of view ``gi`` over the whole grid: the
+        batch index leads for a per-request view under a batch grid; a
+        parameter's block is the same at every grid step."""
+        g = self.groups[gi]
+        if g.param:
+            return lambda *idx, nd=g.ndim: (0,) * nd
+        f = g.index_map(len(self.base_grid),
+                        "lane" if self.lane_grid is not None else "red")
+        if self.batch_grid is None:
+            return f
+        return lambda b, *idx, f=f: (b,) + tuple(f(*idx))
+
     def required_extents(self) -> Dict[str, Tuple[int, ...]]:
         """Per input buffer, the minimal extent along every axis that the
         planned view slices require (the hull over this kernel's groups)."""
         out: Dict[str, Tuple[int, ...]] = {}
         for g in self.groups:
+            if g.param:
+                continue                 # bound by the pipeline, not passed
             need = []
             for j in range(g.ndim):
                 if j == g.blocked_axis:
@@ -855,8 +1020,12 @@ class KernelGroup:
         steps0 = base[0]
         dim1_steps = base[1] if len(base) > 1 else 1
         total = ELEM_BYTES * math.prod(self.output.nstage.pure_extents)
+        once = 0
         for g in self.groups:
             blk = ELEM_BYTES * math.prod(g.block_shape(self.bh, self.bw))
+            if g.param:
+                once += blk              # one block for the whole sweep
+                continue
             if g.pinned:
                 deliveries = 1
             elif self.lane_grid is not None:
@@ -878,7 +1047,7 @@ class KernelGroup:
             else:
                 deliveries = 1
             total += blk * deliveries
-        return self.batch_steps * total
+        return self.batch_steps * total + once
 
     def aligned_blocks(self) -> Dict[str, Tuple[int, ...]]:
         """Compiled-mode (8, 128)-tile-aligned block shapes per stream, the
@@ -902,6 +1071,8 @@ class PipelinePlan:
     nstages: List[NormalizedStage]
     kernels: List[KernelGroup]
     notes: Dict[str, object] = field(default_factory=dict)
+    # each parameter's device layout (empty for a pipeline without any)
+    params: Dict[str, ParamLayout] = field(default_factory=dict)
 
     @property
     def n_stages(self) -> int:
@@ -1458,6 +1629,8 @@ def _build_kernel_group(
     red_resident: bool = True,
     red_chunk: Optional[int] = None,
     lane_price: str = "joint",
+    params: Set[str] = frozenset(),
+    contractions: Optional[Mapping[str, Contraction]] = None,
 ) -> KernelGroup:
     """Build the delivery plan for one kernel (one or more fused stages).
 
@@ -1487,7 +1660,16 @@ def _build_kernel_group(
     :func:`_red_grid_candidate`); ``lane_price`` selects the budget-driven
     lane-width policy — ``"joint"`` (default) prices every fitting
     (bh, bw) pair with the scheduler model, ``"greedy"`` restores the
-    PR 5 widest-first first-fit."""
+    historical widest-first first-fit.
+
+    ``params`` names the pipeline's parameters (each delivered whole, the
+    same block at every grid step) and ``contractions`` the members
+    emitted as MXU contractions.  A kernel that reads a parameter by a
+    member's blocked dim, or whose output stage does not stream, is
+    planned *whole*: one grid step per batch slot, every member evaluated
+    whole into VMEM scratch, so a consumer may read its producer along
+    any axis (a channel contraction reads it along the reduction)."""
+    contractions = dict(contractions or {})
     if lane_price not in ("joint", "greedy"):
         raise ValueError(
             f"lane_price must be 'joint' or 'greedy': {lane_price!r}"
@@ -1495,19 +1677,31 @@ def _build_kernel_group(
     multi = len(members) > 1
     out_ns, out_acc, out_streamed = members[-1]
     names = {ns.name for ns, _, _ in members}
-    if multi and not all(st for _, _, st in members):
+    reads_params = any(
+        la.buffer in params for _, acc, _ in members for la in acc
+    )
+    whole = reads_params and (not out_streamed or any(
+        ax.pure_dim == ns.pure_dims[0]
+        for ns, acc, _ in members for la in acc if la.buffer in params
+        for ax in la.axes
+    ))
+    if multi and not whole and not all(st for _, _, st in members):
         raise FusionInfeasible("fusion requires every member stage to stream")
     for ns, acc, _ in members:
         for la in acc:
             _check_tags(la)
 
     # shift sets are a pure function of the access maps; modes share them
-    shifts_of = _shift_sets(members)
+    # (a whole kernel evaluates every member once, at shift 0)
+    shifts_of = (
+        {ns.name: (0,) for ns, _, _ in members} if whole
+        else _shift_sets(members)
+    )
 
     # -- grid reduction (single-stage kernels only) ---------------------------
     red_grid: Optional[RedGrid] = None
     red_axis_of: Dict[int, Optional[int]] = {}
-    if grid_reduction and not multi and out_streamed:
+    if grid_reduction and not multi and out_streamed and not reads_params:
         cand = _red_grid_candidate(
             out_ns, out_acc, red_grid_threshold, chunk=red_chunk
         )
@@ -1515,7 +1709,7 @@ def _build_kernel_group(
             red_grid, red_axis_of = cand
 
     e0_out = out_ns.pure_extents[0]
-    kernel_streamed = out_streamed
+    kernel_streamed = out_streamed and not whole
 
     # -- lane-blocking candidacy ----------------------------------------------
     # the lane grid tiles the *trailing* pure dim; it needs a streamed
@@ -1524,7 +1718,7 @@ def _build_kernel_group(
     # contract rows have (stride-1 trailing-axis reads, offsets >= 0)
     e1_out = out_ns.pure_extents[-1] if len(out_ns.pure_extents) >= 2 else None
     lane_possible = (
-        lane_block is not False
+        lane_block is not False and not reads_params
         and kernel_streamed and e1_out is not None and red_grid is None
         and all(len(ns.pure_extents) >= 2 for ns, _, _ in members)
     )
@@ -1549,7 +1743,10 @@ def _build_kernel_group(
     ) -> KernelGroup:
         lane = bw is not None
         plans = {
-            ns.name: StagePlan(ns, list(acc), streamed)
+            ns.name: StagePlan(
+                ns, list(acc), streamed and not whole,
+                contraction=contractions.get(ns.name),
+            )
             for ns, acc, streamed in members
         }
         for n, s in shifts_of.items():
@@ -1608,7 +1805,12 @@ def _build_kernel_group(
                     sp.blocked_axis_of.append(0)
                     sp.lane_axis_of.append(len(la.axes) - 1 if lane else None)
                     continue
-                j0 = _blocked_axis(la, sp.d0) if kernel_streamed and sp.streamed else None
+                is_param = la.buffer in params
+                j0 = (
+                    _blocked_axis(la, sp.d0)
+                    if kernel_streamed and sp.streamed and not is_param
+                    else None
+                )
                 jr = red_axis_of.get(k)
                 jL = None
                 if lane:
@@ -1657,6 +1859,9 @@ def _build_kernel_group(
                                 )
                                 binding[bk] = gidx
                 sp.view_binding.append(binding)
+                if is_param:
+                    for gidx in binding.values():
+                        groups[gidx].param = True
 
                 # hull the non-blocked axes of every group this load touches
                 for gidx in set(binding.values()):
@@ -1688,6 +1893,9 @@ def _build_kernel_group(
                 g.base[g.blocked_axis] = g.k0
             if g.lane_axis is not None:
                 g.base[g.lane_axis] = g.l0
+            if g.param:
+                g.base = [0] * g.ndim
+                g.span = list(buffer_shapes[g.buffer])
 
         # -- collapse shifted delivery classes into ring streams -------------
         rings: List[RingStream] = []
@@ -1794,6 +2002,10 @@ def _build_kernel_group(
         scratch_rows = 0                            # scratch scales with bh too
         for ns, _, _ in members[:-1]:
             sp = plans[ns.name]
+            if not kernel_streamed:
+                # a whole kernel holds every member whole, once
+                fixed_bytes += ELEM_BYTES * math.prod(ns.pure_extents)
+                continue
             sh = list(ns.pure_extents[1:])
             if lane and sh:
                 sh[-1] = bw
@@ -2276,6 +2488,25 @@ def build_pipeline_plan(
             )
         accesses = decompose_stage(ns)
         infos.append((ns, accesses, _stream_ok(accesses, ns.pure_dims[0])))
+    params = set(pipe.params)
+    contractions: Dict[str, Contraction] = {}
+    layouts: Dict[str, ParamLayout] = {}
+    if params:
+        for ns, acc, _ in infos:
+            cn = _contraction(ns, acc, params)
+            if cn is not None:
+                contractions[ns.name] = cn
+        layouts = _param_layouts(
+            infos, contractions, {n: shapes[n] for n in params}
+        )
+        infos = [
+            (ns, [
+                layouts[la.buffer].access(la) if la.buffer in layouts else la
+                for la in acc
+            ], st)
+            for ns, acc, st in infos
+        ]
+        shapes.update({n: lay.shape for n, lay in layouts.items()})
     by_name = {ns.name: info for info in infos for ns in [info[0]]}
 
     # consumer map over every stage (host stages pin their inputs in HBM)
@@ -2299,6 +2530,8 @@ def build_pipeline_plan(
         line_buffer=line_buffer, red_resident=red_resident,
         red_chunk=red_chunk, lane_price=lane_price,
     )
+    if params:
+        build_kw.update(params=frozenset(params), contractions=contractions)
 
     def group_infos(root: str) -> List[Tuple]:
         return [by_name[n] for n in order if n in set(members[root])]
@@ -2353,7 +2586,7 @@ def build_pipeline_plan(
             kg.grid = (batch_capacity,) + kg.grid
         notes["batch"] = batch
         notes["batch_capacity"] = batch_capacity
-    return PipelinePlan(pipe, nstages, kernels, notes=notes)
+    return PipelinePlan(pipe, nstages, kernels, notes=notes, params=layouts)
 
 
 __all__ = [
@@ -2367,6 +2600,8 @@ __all__ = [
     "ViewGroup",
     "StagePlan",
     "RedGrid",
+    "Contraction",
+    "ParamLayout",
     "PaddedGrid",
     "KernelGroup",
     "PipelinePlan",

@@ -31,7 +31,7 @@ import hashlib
 import os
 import warnings
 from collections import OrderedDict
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from functools import partial
 from pathlib import Path
 from typing import Dict, Iterable, List, Mapping, Optional
@@ -159,6 +159,13 @@ class PallasPipeline:
     plan: PipelinePlan
     mode: str = "interpret"
     cache_key: Optional[str] = None
+    # each parameter's device array, in the plan's layout, uploaded once
+    params: Dict[str, jax.Array] = field(default_factory=dict)
+
+    @property
+    def param_bytes(self) -> int:
+        """Bytes of parameters this pipeline holds on the device."""
+        return sum(int(a.nbytes) for a in self.params.values())
 
     @property
     def stages(self) -> List[CompiledKernel]:
@@ -312,6 +319,10 @@ def _hash_pipeline_content(h, pipe: Pipeline) -> None:
     ``repr``s make the serialization deterministic."""
     h.update(repr(pipe.output).encode())
     h.update(repr(sorted(pipe.inputs)).encode())
+    for name, value in sorted(pipe.params.items()):
+        arr = np.ascontiguousarray(value, np.float32)
+        h.update(f"param {name}:{arr.shape};".encode())
+        h.update(arr.tobytes())
     for name, box in sorted(pipe.buffer_boxes.items()):
         h.update(f"{name}:{box.dims}:{box.intervals};".encode())
     for ns in normalize_pipeline(pipe):
@@ -553,11 +564,19 @@ def compile_pipeline(
         _warn_lane_carry_degrades(plan)
     if verify is not False:
         assert_plan_verified(plan)
+    params: Dict[str, jax.Array] = {}
+    if plan.params:
+        with tracing.span(tracing.PARAMS):
+            params = {
+                name: jax.device_put(layout.apply(pipe.params[name]))
+                for name, layout in plan.params.items()
+            }
     kernels = []
     for kg in plan.kernels:
         try:
             kernels.append(emit_kernel(
-                kg, mode=mode, vmem_budget=plan.notes["vmem_budget"]
+                kg, mode=mode, vmem_budget=plan.notes["vmem_budget"],
+                params=params,
             ))
         except Exception as e:
             # a certified plan failing to lower is an emitter (or Pallas)
@@ -568,7 +587,9 @@ def compile_pipeline(
                 kernel=kg.stages[-1].name,
                 stage=kg.stage_names[-1] if kg.stage_names else None,
             ) from e
-    pp = PallasPipeline(pipe, kernels, plan, mode=mode, cache_key=key)
+    pp = PallasPipeline(
+        pipe, kernels, plan, mode=mode, cache_key=key, params=params
+    )
     if cache:
         _PIPELINE_CACHE[key] = pp
         while len(_PIPELINE_CACHE) > _PIPELINE_CACHE_MAX:

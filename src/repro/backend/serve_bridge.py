@@ -663,6 +663,7 @@ class PipelineServer:
         """Serving counters, per-fault-class health counters, plus the
         process-wide pipeline-cache stats (hits/misses/evictions/entries)
         the warm path depends on."""
+        held = {id(pp): pp.param_bytes for _p, pp, _kw in self._table.values()}
         return {
             "served": self.served,
             "failed": self.failed,
@@ -670,6 +671,9 @@ class PipelineServer:
             "narrow_dispatches": self.narrow_dispatches,
             "bytes_to_device": self.bytes_to_device,
             "bytes_from_device": self.bytes_from_device,
+            # parameters held on the device (uploaded at registration,
+            # never per dispatch; bytes_to_device counts requests alone)
+            "param_bytes": sum(held.values()),
             "batch_slots": self.batch_slots,
             "shapes": len(self._table),
             "pending": len(self.pending),

@@ -20,3 +20,4 @@ COPY_BACK = "ub.copy_back"        # np.asarray of every kernel's output
 FINITE_CHECK = "ub.finite_check"  # the host NaN/Inf check of live slots
 RECOMPILE = "ub.recompile"        # recovery-ladder recompile
 QUARANTINE = "ub.quarantine"      # one bisection probe dispatch
+PARAMS = "ub.params"              # compile_pipeline: parameters onto the device, once

@@ -111,6 +111,7 @@ RULES: Dict[str, str] = {
     "UB501": "batch grid: leading dim, unit block, occupancy and notes agree",
     "UB502": "batch isolation: no ring/line-buffer state crosses a batch step",
     "UB503": "per-batch exactly-once: each slot evaluates the full per-tile rows",
+    "UB504": "parameters: whole, batch-invariant blocks, fetched and counted once",
 }
 
 
@@ -451,6 +452,21 @@ def _check_scratch_taps(kg: KernelGroup, out: List[PlanViolation]) -> None:
                 ))
                 continue
             psp = kg.stage_plan(pname)
+            pext = psp.nstage.pure_extents
+            if not kg.streamed:
+                # a whole kernel holds the producer as one whole panel:
+                # every tap axis must fit its extents
+                for j, ax in enumerate(la.axes[:len(pext)]):
+                    lo, hi = _tap_interval(ax, red_ext, ext_of)
+                    w = _interval_witness(lo, hi, pext[j])
+                    if w is not None:
+                        out.append(PlanViolation(
+                            "UB103", kg.name,
+                            f"axis {j} taps {pname!r} at [{lo}, {hi}] "
+                            f"outside its whole panel extent {pext[j]}",
+                            stage=sp.name, witness=(w,),
+                        ))
+                continue
             plb = psp.line_buffer
             row_offs = la.axes[0].offsets(red_ext)
             jL = sp.lane_axis_of[k] if lane else None
@@ -507,7 +523,6 @@ def _check_scratch_taps(kg: KernelGroup, out: List[PlanViolation]) -> None:
                                     stage=sp.name, witness=(slot, lslot),
                                 ))
             # inner axes index the producer's panel directly
-            pext = psp.nstage.pure_extents
             for j, ax in enumerate(la.axes):
                 if j == 0 or j == jL or j >= len(pext):
                     continue
@@ -1007,6 +1022,9 @@ def _derive_shift_sets(kg: KernelGroup) -> Dict[str, Set[int]]:
     the raw access maps (the same reverse-topological propagation the
     planner runs, but independent of the stored ``shifts`` fields)."""
     derived: Dict[str, Set[int]] = {kg.stages[-1].name: {0}}
+    if not kg.streamed:
+        # a whole kernel evaluates every member once, whole
+        return {sp.name: {0} for sp in kg.stages}
     for sp in reversed(kg.stages[:-1]):
         req: Set[int] = set()
         for cons in kg.stages:
@@ -1163,6 +1181,9 @@ def _resummed_ws(kg: KernelGroup) -> Tuple[int, int]:
         fixed += r.halo * inner * ELEM_BYTES
     scratch_rows = 0
     for sp in kg.stages[:-1]:
+        if not kg.streamed:
+            fixed += ELEM_BYTES * math.prod(sp.nstage.pure_extents)
+            continue
         sh = list(sp.nstage.pure_extents[1:])
         if lane and sh:
             sh[-1] = kg.bw
@@ -1297,6 +1318,67 @@ def _check_batch(
             ))
 
 
+def _check_params(
+    kg: KernelGroup, shapes: Dict[str, Tuple[int, ...]], params: Set[str],
+    out: List[PlanViolation],
+) -> None:
+    """UB504: a parameter is batch-invariant and held whole.  Every view of
+    a parameter buffer is a parameter view (and only those are): it has no
+    batch dim, no grid dim tiles it, its block is the whole device array,
+    and its index map gives the same block at every grid step — batch
+    steps included — so it is fetched once and its VMEM counted once
+    (single-buffered, UB401)."""
+    grid = kg.grid
+    for gi, g in enumerate(kg.groups):
+        label = _view_label(kg, gi)
+        if g.buffer in params and not g.param:
+            out.append(PlanViolation(
+                "UB504", kg.name,
+                f"parameter {g.buffer!r} is delivered as a per-request "
+                f"view: its block would follow the batch index",
+                view=label,
+            ))
+            continue
+        if g.param and g.buffer not in params:
+            out.append(PlanViolation(
+                "UB504", kg.name,
+                f"{g.buffer!r} is no parameter, but its view is held as "
+                f"one: the requests' batch dim would be dropped",
+                view=label,
+            ))
+            continue
+        if not g.param:
+            continue
+        tiled = [
+            ax for ax in (g.blocked_axis, g.lane_axis, g.red_axis)
+            if ax is not None
+        ]
+        if tiled or g.pinned or g.lane_pinned:
+            out.append(PlanViolation(
+                "UB504", kg.name,
+                f"parameter view is tiled by a grid dim (axes {tiled})",
+                view=label,
+            ))
+        blk = g.block_shape(kg.bh, kg.bw)
+        if tuple(blk) != tuple(shapes.get(g.buffer, ())):
+            out.append(PlanViolation(
+                "UB504", kg.name,
+                f"parameter block {tuple(blk)} is not the whole device "
+                f"array {shapes.get(g.buffer)}",
+                view=label, witness=tuple(blk),
+            ))
+        index = kg.view_index_map(gi)
+        last = tuple(n - 1 for n in grid)
+        first, other = index(*(0,) * len(grid)), index(*last)
+        if first != other:
+            out.append(PlanViolation(
+                "UB504", kg.name,
+                f"parameter block index moves with the grid: {first} at "
+                f"step {(0,) * len(grid)}, {other} at step {last}",
+                view=label, witness=last,
+            ))
+
+
 # ---------------------------------------------------------------------------
 # Entry points
 # ---------------------------------------------------------------------------
@@ -1309,6 +1391,7 @@ def verify_plan(plan: PipelinePlan) -> List[PlanViolation]:
     shapes = {
         n: tuple(b.extents) for n, b in plan.pipeline.buffer_boxes.items()
     }
+    shapes.update({n: lay.shape for n, lay in plan.params.items()})
     budget = int(plan.notes.get("vmem_budget", VMEM_BYTES))
     out: List[PlanViolation] = []
     for kg in plan.kernels:
@@ -1323,6 +1406,7 @@ def verify_plan(plan: PipelinePlan) -> List[PlanViolation]:
         _check_write_once(kg, out)
         _check_eval_accounting(kg, out)
         _check_batch(kg, plan.notes, out)
+        _check_params(kg, shapes, set(plan.params), out)
         _check_budget(kg, budget, out)
     return out
 

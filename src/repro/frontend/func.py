@@ -19,6 +19,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
+import numpy as np
+
 from repro.core.poly import AffineExpr
 from .expr import BinOp, Const, Expr, FuncRef
 
@@ -91,6 +93,7 @@ class Func:
         self.reduction: Optional[Reduction] = None
         self.is_input = False
         self.input_ndim = 0
+        self.param_value = None        # bound array of a parameter input
         # scheduling state
         self.realized = False          # store_root/compute_root; default inline
         self.unroll_factors: Dict[str, int] = {}
@@ -106,6 +109,21 @@ class Func:
         f.input_ndim = ndim
         f.realized = True
         return f
+
+    @staticmethod
+    def param(name: str, value) -> "Func":
+        """A parameter: an input bound to ``value`` (loop order, outermost
+        first) when the app is built.  It is not part of the request
+        contract (``Pipeline.inputs``): the compiled pipeline holds it on
+        the device, shared by every request."""
+        value = np.asarray(value, np.float32)
+        f = Func.input(name, value.ndim)
+        f.param_value = value
+        return f
+
+    @property
+    def is_param(self) -> bool:
+        return self.param_value is not None
 
     # -- algorithm ----------------------------------------------------------------
     def __getitem__(self, idx) -> FuncRef:

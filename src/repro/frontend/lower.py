@@ -93,6 +93,9 @@ class Pipeline:
     output: str
     buffer_boxes: Dict[str, Box]    # realized buffer name -> element box
     host_stages: List[Stage] = field(default_factory=list)
+    # parameters: inputs bound to arrays at build time (loop order), held
+    # by the compiled pipeline instead of arriving with each request
+    params: Dict[str, "object"] = field(default_factory=dict)
 
     def stage(self, name: str) -> Stage:
         for s in self.stages + self.host_stages:
@@ -167,9 +170,23 @@ def lower_pipeline(
         stage = _make_stage(f, box, inlined_exprs, by_name)
         (host_stages if f.on_host else stages).append(stage)
 
-    inputs = [n for n in order if by_name[n].is_input]
+    inputs = [n for n in order if by_name[n].is_input and not by_name[n].is_param]
     buffer_boxes = {n: required[n] for n in required}
-    return Pipeline(stages, inputs, output.name, buffer_boxes, host_stages)
+    params = {}
+    for n in order:
+        f = by_name[n]
+        if not f.is_param:
+            continue
+        # a parameter is held whole: its box is the bound array's extents
+        full = Box(_loop_dims(f), tuple((0, e - 1) for e in f.param_value.shape))
+        if required[n].hull(full) != full:
+            raise ValueError(
+                f"parameter {n!r} of shape {f.param_value.shape} is read at "
+                f"{required[n].intervals}, outside its extents"
+            )
+        buffer_boxes[n] = full
+        params[n] = f.param_value
+    return Pipeline(stages, inputs, output.name, buffer_boxes, host_stages, params)
 
 
 # -- helpers ------------------------------------------------------------------
@@ -455,7 +472,7 @@ def execute_pipeline(
     import numpy as np
 
     values: Dict[str, Dict[Tuple[int, ...], float]] = {}
-    for name, arr in input_arrays.items():
+    for name, arr in {**pipe.params, **input_arrays}.items():
         a = np.asarray(arr)
         values[name] = {}
         # buffer element coords are absolute; required boxes may not start
