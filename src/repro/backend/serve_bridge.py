@@ -65,6 +65,7 @@ and the process-wide pipeline-cache counters in one dict.
 
 from __future__ import annotations
 
+import functools
 import time
 import warnings
 from collections import deque
@@ -76,10 +77,13 @@ from typing import (
     List,
     Mapping,
     Optional,
+    Sequence,
     Tuple,
     Union,
 )
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.frontend.lower import Pipeline
@@ -114,6 +118,16 @@ from .runner import (
 # int8, int16, bool, float16, float32) and is widened there; int32, int64
 # and float64 batches are cast to float32 on the host (runner.stage_dtype)
 _NUMERIC_KINDS = frozenset("fiub")
+
+
+@jax.jit
+def ub_finite_slots(outs: Sequence[jax.Array]) -> jax.Array:
+    """Per slot, whether every element of every array of ``outs`` (slots
+    on the leading axis) is finite, on the device (module
+    ``jit_ub_finite_slots``).  Each array reduces over its other axes in
+    the layout it has: a reshape first could cost a relayout copy."""
+    flags = [jnp.isfinite(a).all(axis=tuple(range(1, a.ndim))) for a in outs]
+    return functools.reduce(jnp.logical_and, flags)
 
 
 @dataclass
@@ -225,6 +239,8 @@ class PipelineServer:
         self.dispatches = 0
         # dispatches whose inputs crossed narrower than float32
         self.narrow_dispatches = 0
+        # dispatches whose per-slot finite flags the device computed
+        self.finite_on_device_dispatches = 0
         # host<->device traffic of every dispatch, filler slots included
         self.bytes_to_device = 0
         self.bytes_from_device = 0
@@ -396,10 +412,11 @@ class PipelineServer:
 
     def _dispatch(
         self, pipe: Pipeline, pp: PallasPipeline, reqs: List[TileRequest]
-    ) -> Dict[str, np.ndarray]:
+    ) -> Tuple[Dict[str, np.ndarray], jax.Array]:
         """One padded-to-capacity batched execution; returns per-kernel
-        stacked host arrays.  Raises whatever the kernels raise — fault
-        handling is the caller's (``_service``) job."""
+        stacked host arrays and the device's per-slot finite flags
+        (:func:`ub_finite_slots`).  Raises whatever the kernels raise —
+        fault handling is the caller's (``_service``) job."""
         with tracing.span(tracing.STACK):
             dtypes = {
                 n: stage_dtype([np.asarray(r.inputs[n]).dtype for r in reqs])
@@ -423,6 +440,11 @@ class PipelineServer:
         self.dispatches += 1
         if any(a.dtype != np.float32 for a in ins.values()):
             self.narrow_dispatches += 1
+        # flagged on what the seam returned (the fault injectors' arrays
+        # too), launched before the copy-back so the device reduces while
+        # the host copies
+        finite = ub_finite_slots(tuple(bufs[ck.name] for ck in pp.kernels))
+        self.finite_on_device_dispatches += 1
         # one host conversion per kernel per dispatch — slicing per slot on
         # the jax array would pay a separate device sync per tile
         with tracing.span(tracing.COPY_BACK):
@@ -434,22 +456,16 @@ class PipelineServer:
         self.bytes_from_device += copied
         # keep this dispatch's host buffers on the heap for the next one
         pin_host_allocator(staged + copied)
-        return outs
+        return outs, finite
 
     @staticmethod
-    def _poisoned_slots(
-        outs: Dict[str, np.ndarray], n_live: int
-    ) -> List[int]:
-        """Live slot indices whose outputs contain NaN/Inf (filler slots
-        run on zero inputs and are never read back)."""
-        bad: List[int] = []
+    def _poisoned_slots(finite: jax.Array, n_live: int) -> List[int]:
+        """Live slot indices whose outputs contain NaN/Inf, read from the
+        dispatch's per-slot device flags (filler slots run on zero inputs
+        and are never read back)."""
         with tracing.span(tracing.FINITE_CHECK):
-            for b in range(n_live):
-                for arr in outs.values():
-                    if not np.isfinite(arr[b]).all():
-                        bad.append(b)
-                        break
-        return bad
+            ok = np.asarray(finite)[:n_live]
+        return [int(b) for b in np.flatnonzero(~ok)]
 
     def _complete(
         self, reqs: List[TileRequest], outs: Dict[str, np.ndarray]
@@ -504,7 +520,7 @@ class PipelineServer:
         self.fault_counters["quarantine_dispatches"] += 1
         try:
             with tracing.span(tracing.QUARANTINE):
-                outs = self._dispatch(pipe, pp, reqs)
+                outs, finite = self._dispatch(pipe, pp, reqs)
         except Exception as e:
             if len(reqs) == 1:
                 self.fault_counters["poisoned_tiles"] += 1
@@ -518,8 +534,7 @@ class PipelineServer:
             self._quarantine(key, reqs[:mid])
             self._quarantine(key, reqs[mid:])
             return
-        bad = self._poisoned_slots(outs, len(reqs))
-        if not bad:
+        if not self._poisoned_slots(finite, len(reqs)):
             self._complete(reqs, outs)
             return
         if len(reqs) == 1:
@@ -558,15 +573,15 @@ class PipelineServer:
         quarantine bisection.  On return every request in ``reqs`` is
         ``done`` — completed or failed closed with a named error."""
         pipe, pp, _kw = self._table[key]
-        outs: Optional[Dict[str, np.ndarray]] = None
+        done: Optional[Tuple[Dict[str, np.ndarray], jax.Array]] = None
         try:
-            outs = self._dispatch(pipe, pp, reqs)
+            done = self._dispatch(pipe, pp, reqs)
         except Exception as first_err:
             self.fault_counters["dispatch_failures"] += 1
             for heuristic in (False, True):
                 try:
                     fresh = self._recompile(key, heuristic=heuristic)
-                    outs = self._dispatch(pipe, fresh, reqs)
+                    done = self._dispatch(pipe, fresh, reqs)
                 except Exception:
                     continue
                 self.fault_counters["degraded_dispatches"] += 1
@@ -579,11 +594,12 @@ class PipelineServer:
                     stacklevel=4,
                 )
                 break
-        if outs is None:
+        if done is None:
             # ladder exhausted: isolate the poison per tile
             self._quarantine(key, reqs)
             return
-        if self._poisoned_slots(outs, len(reqs)):
+        outs, finite = done
+        if self._poisoned_slots(finite, len(reqs)):
             # non-finite output in a live slot: nothing from this dispatch
             # is trustworthy — re-serve every tile from clean bisection
             # dispatches so healthy tiles stay bit-exact
@@ -669,6 +685,7 @@ class PipelineServer:
             "failed": self.failed,
             "dispatches": self.dispatches,
             "narrow_dispatches": self.narrow_dispatches,
+            "finite_on_device_dispatches": self.finite_on_device_dispatches,
             "bytes_to_device": self.bytes_to_device,
             "bytes_from_device": self.bytes_from_device,
             # parameters held on the device (uploaded at registration,
@@ -682,4 +699,4 @@ class PipelineServer:
         }
 
 
-__all__ = ["TileRequest", "PipelineServer"]
+__all__ = ["TileRequest", "PipelineServer", "ub_finite_slots"]

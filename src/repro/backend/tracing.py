@@ -17,7 +17,7 @@ STACK = "ub.stack"                # pad to slots + np.stack at the staged dtype
 TO_DEVICE = "ub.to_device"        # PallasPipeline.run: inputs onto the device
 KERNEL = "ub.kernel."             # + kernel name: one host-side launch
 COPY_BACK = "ub.copy_back"        # np.asarray of every kernel's output
-FINITE_CHECK = "ub.finite_check"  # the host NaN/Inf check of live slots
+FINITE_CHECK = "ub.finite_check"  # read of the device's per-slot NaN/Inf flags
 RECOMPILE = "ub.recompile"        # recovery-ladder recompile
 QUARANTINE = "ub.quarantine"      # one bisection probe dispatch
 PARAMS = "ub.params"              # compile_pipeline: parameters onto the device, once
