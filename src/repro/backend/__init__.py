@@ -29,28 +29,23 @@ from .errors import (
     ServeError,
     TunedModeMismatchWarning,
 )
-from .codegen import (
-    CompiledKernel,
-    CompiledStage,
-    compile_stage,
-    emit_kernel,
-    resolve_mode,
-)
+from .codegen import CompiledKernel, emit_kernel, resolve_mode
 from .plan import (
     FusionInfeasible,
     KernelGroup,
     LineBuffer,
     PaddedGrid,
     PipelinePlan,
+    PlanOptions,
     RedGrid,
     RingStream,
     StagePlan,
+    TUNABLE_KEYS,
     ViewGroup,
     build_pipeline_plan,
     scheduler_cost,
 )
 from .runner import (
-    TUNABLE_KEYS,
     PallasPipeline,
     clear_pipeline_cache,
     compile_pipeline,
@@ -78,15 +73,14 @@ __all__ = [
     "UnsupportedAccessError",
     "decompose_stage",
     "CompiledKernel",
-    "CompiledStage",
     "ViewGroup",
-    "compile_stage",
     "emit_kernel",
     "FusionInfeasible",
     "KernelGroup",
     "LineBuffer",
     "PaddedGrid",
     "PipelinePlan",
+    "PlanOptions",
     "RedGrid",
     "RingStream",
     "StagePlan",

@@ -7,8 +7,8 @@ model (``plan.scheduler_cost`` / ``core/scheduling.raster_cycles``) and
 measurement, in the exo / SYS_ATL spirit of the schedule as a first-class
 searchable object:
 
-1. **enumerate** candidate schedules over the planner's tunable knobs —
-   joint (bh, bw) pairs (``lane_width_candidates(order="joint")``), the
+1. **enumerate** candidate schedules over the planner's tunable options —
+   joint (bh, bw) pairs (``lane_width_candidates``), the
    fusion cut, ``line_buffer`` mode, and the grid-reduction chunk,
 2. **prune** with the cycle model: every candidate plan is built
    symbolically (no kernel is traced) and ranked by its summed
@@ -38,21 +38,23 @@ import os
 import time
 import warnings
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Mapping, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.core.ubplan import VMEM_BYTES, lane_width_candidates
+from repro.core.ubplan import lane_width_candidates
 from repro.frontend.lower import Pipeline, normalize_pipeline
 
 from .access import UnsupportedAccessError
 from .errors import ScheduleDBCorruptWarning
-from .plan import FusionInfeasible, PipelinePlan, build_pipeline_plan
-from .runner import (
+from .plan import (
     TUNABLE_KEYS,
-    compile_pipeline,
-    schedule_db_key,
+    FusionInfeasible,
+    PipelinePlan,
+    PlanOptions,
+    build_pipeline_plan,
 )
+from .runner import compile_pipeline, schedule_db_key
 from .verify import verify_plan
 
 # a schedule is a dict over the tunable knobs only (TUNABLE_KEYS); the
@@ -208,7 +210,7 @@ def _valid_entry_or_reason(entry: object) -> Optional[str]:
 
 
 def _serveable_entry(
-    pipe: Pipeline, plan_kwargs: Mapping, db: object, stacklevel: int
+    pipe: Pipeline, options: PlanOptions, db: object, stacklevel: int
 ) -> Optional[Dict]:
     """Shared lookup with degradation: a corrupt db or malformed row is a
     *miss* plus a named :class:`ScheduleDBCorruptWarning` — the caller
@@ -223,7 +225,7 @@ def _serveable_entry(
             stacklevel=stacklevel,
         )
         return None
-    key = schedule_db_key(pipe, plan_kwargs)
+    key = schedule_db_key(pipe, options)
     entry = resolved.lookup_entry(key)
     if entry is None:
         return None
@@ -240,27 +242,27 @@ def _serveable_entry(
 
 
 def lookup_schedule(
-    pipe: Pipeline, plan_kwargs: Mapping, db: object = "auto"
+    pipe: Pipeline, options: PlanOptions = PlanOptions(), db: object = "auto"
 ) -> Optional[Schedule]:
-    """The ``compile_pipeline(tune=...)`` hook: stored winning schedule for
-    this pipeline + non-tunable kwargs, or ``None`` on a db miss (the
-    caller falls back to the heuristic planner).  A corrupt db or
-    malformed row is a miss with a :class:`ScheduleDBCorruptWarning`."""
-    entry = _serveable_entry(pipe, plan_kwargs, db, stacklevel=3)
+    """Stored winning schedule for this pipeline under the non-tunable
+    side of ``options``, or ``None`` on a db miss (the caller falls back
+    to the heuristic planner).  A corrupt db or malformed row is a miss
+    with a :class:`ScheduleDBCorruptWarning`."""
+    entry = _serveable_entry(pipe, options, db, stacklevel=3)
     if entry is None:
         return None
     return dict(entry["schedule"])
 
 
 def lookup_schedule_entry(
-    pipe: Pipeline, plan_kwargs: Mapping, db: object = "auto"
+    pipe: Pipeline, options: PlanOptions = PlanOptions(), db: object = "auto"
 ) -> Optional[Dict]:
     """Like :func:`lookup_schedule` but returns the full stored row — the
     runner reads ``entry["mode"]`` to warn when an interpret-measured
     winner is served to a compiled-mode compile.  ``stacklevel`` walks
     lookup → ``compile_pipeline`` → the user's compile call, so the
     degradation warning points at the tuned compile that degraded."""
-    return _serveable_entry(pipe, plan_kwargs, db, stacklevel=4)
+    return _serveable_entry(pipe, options, db, stacklevel=4)
 
 
 # ---------------------------------------------------------------------------
@@ -274,7 +276,7 @@ def _cdiv(a: int, b: int) -> int:
 
 def enumerate_candidates(
     pipe: Pipeline,
-    plan_kwargs: Optional[Mapping] = None,
+    options: PlanOptions = PlanOptions(),
     max_candidates: int = 32,
 ) -> List[Schedule]:
     """Deterministic candidate schedules for one pipeline, heuristic first.
@@ -282,7 +284,7 @@ def enumerate_candidates(
     The axes come straight from the lowered extents (no plan is built):
     block heights (powers of two up to 64 plus the low-padding ceil
     divisions of the output row extent), joint lane widths
-    (``lane_width_candidates(order="joint")``), the ``line_buffer`` mode,
+    (``lane_width_candidates``), the ``line_buffer`` mode,
     the fusion cut (multi-stage pipelines only), and grid-reduction chunks
     (pipelines with a large leading reduction dim only).  Single knobs are
     tried before pairs so a truncated list still spans every axis; the
@@ -296,11 +298,7 @@ def enumerate_candidates(
     red_ext = max(
         (ns.red_extents[0] for ns in nstages if ns.red_dims), default=0
     )
-    threshold = dict(plan_kwargs or {}).get("red_grid_threshold")
-    if threshold is None:
-        from .plan import RED_GRID_THRESHOLD
-
-        threshold = RED_GRID_THRESHOLD
+    threshold = options.red_grid_threshold
 
     bh_pool: List[int] = []
     b = 2
@@ -314,7 +312,7 @@ def enumerate_candidates(
 
     bw_pool: List[int] = []
     if e1 is not None and e1 > 8:
-        bw_pool = lane_width_candidates(e1, order="joint")[:3]
+        bw_pool = lane_width_candidates(e1)[:3]
 
     rc_pool: List[int] = []
     if red_ext >= threshold:
@@ -447,7 +445,7 @@ def search(
     label: str = "pipeline",
     db: object = None,
     mode: str = "interpret",
-    plan_kwargs: Optional[Mapping] = None,
+    options: PlanOptions = PlanOptions(),
     max_candidates: int = 32,
     measure_top: int = 8,
     measure: bool = True,
@@ -460,9 +458,9 @@ def search(
 ) -> TuneResult:
     """Autotune one pipeline: enumerate → prune → certify → measure → store.
 
-    ``plan_kwargs`` fixes the non-tunable side of the problem (budget,
-    batching, alignment); it must not name tunable knobs — those are the
-    search's to vary.  ``measure_top`` caps how many certified candidates
+    ``options`` fixes the non-tunable side of the problem (budget,
+    batching, alignment); it must leave the tunable options at their
+    defaults — those are the search's to vary.  ``measure_top`` caps how many certified candidates
     are actually compiled and timed (the heuristic plan is always one of
     them); ``measure=False`` skips execution entirely and the winner is
     the modeled-cheapest certified candidate — fully deterministic.
@@ -474,11 +472,10 @@ def search(
     default db — the winner is stored and the db saved; ``None`` skips
     persistence.  Returns the :class:`TuneResult` audit trail either way.
     """
-    fixed = dict(plan_kwargs or {})
-    bad = sorted(set(fixed) & set(TUNABLE_KEYS))
+    bad = [k for k, _ in options.items() if k in TUNABLE_KEYS]
     if bad:
         raise ValueError(
-            f"plan_kwargs fixes tunable knobs {bad}; pass a narrower "
+            f"options fix tunable knobs {bad}; pass a narrower "
             f"search via max_candidates instead"
         )
     say = log or (lambda _msg: None)
@@ -486,10 +483,10 @@ def search(
     # -- enumerate + symbolic build + model pruning --------------------------
     candidates: List[Candidate] = []
     seen_fp: set = set()
-    for sched in enumerate_candidates(pipe, fixed, max_candidates):
+    for sched in enumerate_candidates(pipe, options, max_candidates):
         cand = Candidate(schedule=sched)
         try:
-            cand.plan = build_pipeline_plan(pipe, **{**fixed, **sched})
+            cand.plan = build_pipeline_plan(pipe, options, **sched)
         except (FusionInfeasible, UnsupportedAccessError, ValueError) as e:
             say(f"{label}: {sched or '{heuristic}'} does not plan: {e}")
             continue
@@ -544,7 +541,8 @@ def search(
         for cand in certified:
             t0 = time.perf_counter()
             pp = compile_pipeline(
-                pipe, cache=True, mode=mode, **{**fixed, **cand.schedule}
+                pipe, cache=True, mode=mode,
+                **dict(options.items(), **cand.schedule),
             )
             got = pp.run(inputs)
             got[out_name].block_until_ready()
@@ -570,7 +568,7 @@ def search(
             ),
         )
 
-    key = schedule_db_key(pipe, fixed)
+    key = schedule_db_key(pipe, options)
     entry = {
         "app": label,
         "schedule": dict(winner.schedule),

@@ -41,20 +41,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.ubplan import LANE, KernelPlan, VMEM_BYTES
+from repro.core.ubplan import LANE, KernelPlan
 from repro.frontend.expr import BinOp, Const, Expr, FuncRef, IterVal, Select
 from repro.frontend.lower import NormalizedStage
 
 from . import tracing
-from .access import UnsupportedAccessError, decompose_stage
-from .plan import (
-    KernelGroup,
-    RED_GRID_THRESHOLD,
-    StagePlan,
-    ViewGroup,
-    _build_kernel_group,
-    _stream_ok,
-)
+from .access import UnsupportedAccessError
+from .plan import KernelGroup, StagePlan, ViewGroup
 
 # sentinel scratch-dict key space for input ring buffers (cannot collide
 # with (stage name, shift) keys)
@@ -958,16 +951,15 @@ class _BoundParams:
 def emit_kernel(
     kg: KernelGroup,
     *,
-    interpret: bool = True,
-    mode: Optional[str] = None,
-    vmem_budget: int = VMEM_BYTES,
+    mode: str = "interpret",
+    vmem_budget: int,
     params: Optional[Mapping[str, jax.Array]] = None,
 ) -> CompiledKernel:
     """Emit one executable ``pallas_call`` from a planned kernel group.
     All shape information (and its bounds validation) lives in the plan.
 
-    ``mode`` (when given) supersedes ``interpret``: ``"interpret"`` |
-    ``"compiled"`` | ``"auto"`` (see :func:`resolve_mode`).  The emitted
+    ``mode``: ``"interpret"`` | ``"compiled"`` | ``"auto"`` (see
+    :func:`resolve_mode`).  The emitted
     closure is wrapped in ``jax.jit``, so repeated calls with same-shaped
     buffers reuse the first call's trace — binding new buffers to an
     already-emitted kernel is cheap (the plan/emit/bind split).
@@ -980,11 +972,8 @@ def emit_kernel(
     ``params`` maps each parameter the kernel reads to its device array
     (in the plan's layout); the jitted program binds them, so it is called
     and lowered with the per-request buffers of ``buffer_order`` alone."""
-    if mode is not None:
-        mode = resolve_mode(mode)
-        interpret = mode != "compiled"
-    else:
-        mode = "interpret" if interpret else "compiled"
+    mode = resolve_mode(mode)
+    interpret = mode != "compiled"
     ctxs = {sp.name: _StageCtx(kg, sp) for sp in kg.stages}
     segments = {} if kg.lane_grid is not None else _lane_segments(kg)
     for ctx in ctxs.values():
@@ -1359,55 +1348,9 @@ def emit_kernel(
     )
 
 
-def compile_stage(
-    nstage: NormalizedStage,
-    buffer_shapes: Mapping[str, Tuple[int, ...]],
-    *,
-    interpret: bool = True,
-    mode: Optional[str] = None,
-    block_h: Optional[int] = None,
-    block_w: Optional[int] = None,
-    vmem_budget: int = VMEM_BYTES,
-    grid_reduction: bool = False,
-    red_grid_threshold: int = RED_GRID_THRESHOLD,
-    cost_model: str = "scheduler",
-    line_buffer: object = "auto",
-    red_resident: bool = True,
-) -> CompiledKernel:
-    """Compile one normalized stage to a Pallas kernel (plan + emit)."""
-    from repro.frontend.expr import refs_in
-
-    if nstage.init is not None and refs_in(nstage.init):
-        raise UnsupportedAccessError(
-            f"{nstage.name}: reduction init with buffer reads is not supported"
-        )
-    accesses = decompose_stage(nstage)
-    streamed = _stream_ok(accesses, nstage.pure_dims[0])
-    kg = _build_kernel_group(
-        [(nstage, accesses, streamed)],
-        buffer_shapes,
-        block_h=block_h,
-        block_w=block_w,
-        vmem_budget=vmem_budget,
-        cost_model=cost_model,
-        grid_reduction=grid_reduction,
-        red_grid_threshold=red_grid_threshold,
-        line_buffer=line_buffer,
-        red_resident=red_resident,
-    )
-    return emit_kernel(
-        kg, interpret=interpret, mode=mode, vmem_budget=vmem_budget
-    )
-
-
-# pre-refactor name: a single-stage CompiledKernel is the old CompiledStage
-CompiledStage = CompiledKernel
-
 __all__ = [
     "CompiledKernel",
-    "CompiledStage",
     "ViewGroup",
-    "compile_stage",
     "emit_kernel",
     "eval_trace",
     "resolve_mode",

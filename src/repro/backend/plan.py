@@ -62,8 +62,8 @@ Planning passes, in order:
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Mapping, Optional, Sequence, Set, Tuple
+from dataclasses import asdict, dataclass, field, fields, replace
+from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -109,6 +109,83 @@ MAX_RED_CHUNK = 128
 # which is what makes short-grid strided rings (few steps to amortize the
 # warm-up against) lose to plain per-tap delivery under ``auto``.
 RING_STEP_OVERHEAD_CYCLES = 8
+
+
+def _tunable(default: object) -> Any:
+    """A plan option the autotuner searches: part of the schedule, not of
+    the problem (see :data:`TUNABLE_KEYS`)."""
+    return field(default=default, metadata={"tunable": True})
+
+
+@dataclass(frozen=True)
+class PlanOptions:
+    """Every option of planning, each with its one default.
+
+    * ``block_h`` / ``block_w``: force the row block height / a lane-blocked
+      2-D grid of that width (``None``: the planner picks; the lane grid
+      then engages only when a one-row full-width panel overflows VMEM).
+      ``lane_block=False`` forbids the lane grid.
+    * ``fuse``: merge producer/consumer chains into one kernel;
+      ``grid_reduction`` chunks a reduction of at least
+      ``red_grid_threshold`` into the grid, ``red_chunk`` sets its chunk,
+      and ``red_resident`` keeps small reduction-invariant operands whole
+      in VMEM.
+    * ``line_buffer``: carry fused intermediates and shifted inputs across
+      grid steps (``True``), recompute them (``False``), or let the
+      scheduler cost model pick per chain (``"auto"``).
+    * ``vmem_budget`` bounds every kernel's working set; ``align_tpu``
+      plans (8, 128)-tileable blocks (forced by compiled mode).
+    * ``batch`` / ``batch_capacity``: a leading grid dim of ``batch`` valid
+      tiles in ``batch_capacity`` slots (default ``batch``).
+
+    The plan cache key and the schedule-db key hash :meth:`items`, the
+    options set away from their defaults, so an explicit default and an
+    omitted option name one plan."""
+
+    block_h: Optional[int] = _tunable(None)
+    block_w: Optional[int] = _tunable(None)
+    lane_block: object = "auto"
+    fuse: bool = _tunable(True)
+    grid_reduction: bool = True
+    red_grid_threshold: int = RED_GRID_THRESHOLD
+    vmem_budget: int = VMEM_BYTES
+    align_tpu: bool = False
+    line_buffer: object = _tunable("auto")
+    red_resident: bool = True
+    batch: Optional[int] = None
+    batch_capacity: Optional[int] = None
+    red_chunk: Optional[int] = _tunable(None)
+
+    def items(self) -> List[Tuple[str, object]]:
+        """``(name, value)`` of every option away from its default, by
+        name."""
+        return [
+            (f.name, getattr(self, f.name))
+            for f in sorted(fields(self), key=lambda f: f.name)
+            if getattr(self, f.name) != f.default
+        ]
+
+    def heuristic(self) -> "PlanOptions":
+        """These options with every tunable one at its default: the
+        planning problem without a schedule."""
+        return replace(self, **{k: _DEFAULTS[k] for k in TUNABLE_KEYS})
+
+    def tuned(self, schedule: Mapping[str, object]) -> "PlanOptions":
+        """Apply a stored ``schedule`` to the tunable options still at
+        their default: an option the caller set beats the schedule."""
+        return replace(self, **{
+            k: v for k, v in schedule.items()
+            if k in TUNABLE_KEYS and getattr(self, k) == _DEFAULTS[k]
+        })
+
+
+_DEFAULTS = {f.name: f.default for f in fields(PlanOptions)}
+# the options a stored schedule (backend/autotune) may set: the
+# autotuner's search axes.  The rest (budget, batching, alignment) pose the
+# problem and key the schedule db.
+TUNABLE_KEYS = frozenset(
+    f.name for f in fields(PlanOptions) if f.metadata.get("tunable")
+)
 
 
 class FusionInfeasible(PlanError):
@@ -1616,51 +1693,34 @@ def _lane_ring_rewrite(
 def _build_kernel_group(
     members: List[Tuple[NormalizedStage, List[LoadAccess], bool]],
     buffer_shapes: Mapping[str, Tuple[int, ...]],
+    opts: PlanOptions,
     *,
-    block_h: Optional[int] = None,
-    block_w: Optional[int] = None,
-    lane_block: object = "auto",
-    vmem_budget: int = VMEM_BYTES,
-    cost_model: str = "scheduler",
-    align_tpu: bool = False,
-    grid_reduction: bool = True,
-    red_grid_threshold: int = RED_GRID_THRESHOLD,
-    line_buffer: object = "auto",
-    red_resident: bool = True,
-    red_chunk: Optional[int] = None,
-    lane_price: str = "joint",
     params: Set[str] = frozenset(),
     contractions: Optional[Mapping[str, Contraction]] = None,
 ) -> KernelGroup:
     """Build the delivery plan for one kernel (one or more fused stages).
 
-    ``line_buffer`` selects the recompute-vs-carry mode for fused
+    ``opts.line_buffer`` selects the recompute-vs-carry mode for fused
     intermediates and shifted input deliveries: ``False`` recomputes fused
     panels per demanded shift and streams one view per tap (the PR 2
     scheme), ``True`` carries halo rows in cross-grid-step rings wherever
     structurally feasible (``halo <= bh``), and ``"auto"`` builds both
     plans and keeps the one the scheduler cost model prices cheaper.  When
-    no scheduler pricing exists (explicit ``block_h``, or a different
-    ``cost_model``), ``"auto"`` prefers carry wherever feasible — it is
-    strictly less traffic and at most equal compute — and tags the plan
+    the block height is not the model's choice (explicit ``block_h``),
+    ``"auto"`` prefers carry wherever feasible — it is strictly less
+    traffic and at most equal compute — and tags the plan
     ``linebuf_mode="carry-unpriced"``.
 
-    ``block_w`` forces a lane-blocked 2-D grid (``ceil(e0/bh)`` row panels
-    x ``ceil(e1/bw)`` lane blocks); without it the planner engages the lane
-    grid automatically when even a one-row full-width panel exceeds the
-    VMEM budget.  Lane-blocked kernels run in recompute mode (rings and
-    line buffers only span grid dim 0) and are mutually exclusive with
+    ``opts.block_w`` forces a lane-blocked 2-D grid (``ceil(e0/bh)`` row
+    panels x ``ceil(e1/bw)`` lane blocks); without it the planner engages
+    the lane grid automatically when even a one-row full-width panel
+    exceeds the VMEM budget, pricing every fitting (bh, bw) pair with the
+    scheduler model.  Lane-blocked kernels are mutually exclusive with
     grid-level reductions.
 
     Raises :class:`FusionInfeasible` when a multi-stage group violates a
     structural constraint or cannot fit VMEM at any block height; a
     single-stage group always plans (matching the pre-refactor backend).
-
-    ``red_chunk`` overrides the grid-reduction chunk size (see
-    :func:`_red_grid_candidate`); ``lane_price`` selects the budget-driven
-    lane-width policy — ``"joint"`` (default) prices every fitting
-    (bh, bw) pair with the scheduler model, ``"greedy"`` restores the
-    historical widest-first first-fit.
 
     ``params`` names the pipeline's parameters (each delivered whole, the
     same block at every grid step) and ``contractions`` the members
@@ -1670,10 +1730,6 @@ def _build_kernel_group(
     whole into VMEM scratch, so a consumer may read its producer along
     any axis (a channel contraction reads it along the reduction)."""
     contractions = dict(contractions or {})
-    if lane_price not in ("joint", "greedy"):
-        raise ValueError(
-            f"lane_price must be 'joint' or 'greedy': {lane_price!r}"
-        )
     multi = len(members) > 1
     out_ns, out_acc, out_streamed = members[-1]
     names = {ns.name for ns, _, _ in members}
@@ -1701,9 +1757,9 @@ def _build_kernel_group(
     # -- grid reduction (single-stage kernels only) ---------------------------
     red_grid: Optional[RedGrid] = None
     red_axis_of: Dict[int, Optional[int]] = {}
-    if grid_reduction and not multi and out_streamed and not reads_params:
+    if opts.grid_reduction and not multi and out_streamed and not reads_params:
         cand = _red_grid_candidate(
-            out_ns, out_acc, red_grid_threshold, chunk=red_chunk
+            out_ns, out_acc, opts.red_grid_threshold, chunk=opts.red_chunk
         )
         if cand is not None:
             red_grid, red_axis_of = cand
@@ -1718,7 +1774,7 @@ def _build_kernel_group(
     # contract rows have (stride-1 trailing-axis reads, offsets >= 0)
     e1_out = out_ns.pure_extents[-1] if len(out_ns.pure_extents) >= 2 else None
     lane_possible = (
-        lane_block is not False and not reads_params
+        opts.lane_block is not False and not reads_params
         and kernel_streamed and e1_out is not None and red_grid is None
         and all(len(ns.pure_extents) >= 2 for ns, _, _ in members)
     )
@@ -1727,7 +1783,7 @@ def _build_kernel_group(
         try:
             lane_shifts_of = _lane_shift_sets(members)
         except FusionInfeasible:
-            if block_w is not None:
+            if opts.block_w is not None:
                 # forced lane blocking must not be silently dropped: fail
                 # this *fusion* so the pipeline planner falls back to
                 # per-stage kernels, each lane-blocked on its own
@@ -1931,12 +1987,12 @@ def _build_kernel_group(
 
         # -- grid reductions: keep small invariant operands whole in VMEM ----
         # (chunk re-delivery once per row panel is pure refetch traffic)
-        if red_grid is not None and red_resident:
+        if red_grid is not None and opts.red_resident:
             for g in groups:
                 if (
                     g.blocked_axis is None and g.red_axis is not None
                     and not g.pinned
-                    and ELEM_BYTES * math.prod(g.span) <= vmem_budget // 4
+                    and ELEM_BYTES * math.prod(g.span) <= opts.vmem_budget // 4
                 ):
                     g.resident = True
 
@@ -2032,7 +2088,7 @@ def _build_kernel_group(
         # trusts cycle comparisons between model-chosen heights, exactly
         # as before.
         cost = None
-        if kernel_streamed and cost_model == "scheduler":
+        if kernel_streamed:
             stmts_per_row = 0
             carry_stmts = 0
             warmup_stmts = 0
@@ -2114,20 +2170,21 @@ def _build_kernel_group(
             )
         if not kernel_streamed:
             bh = e0_out
-        elif block_h is not None:
-            if block_h < 1:
+        elif opts.block_h is not None:
+            if opts.block_h < 1:
                 raise ValueError(f"{out_ns.name}: block_h must be >= 1")
             # any block height plans: a non-divisor runs on a padded grid
             # whose masked tail block hangs past the edge (blocks above the
             # extent degenerate to one padded step, so clamp to the extent)
-            bh = min(block_h, e0_out)
+            bh = min(opts.block_h, e0_out)
         else:
             bh = plan_affine_stage(
                 e0_out, bytes_per_row, fixed_bytes,
-                vmem_budget=vmem_budget, cost=cost, align_tpu=align_tpu,
+                vmem_budget=opts.vmem_budget, cost=cost,
+                align_tpu=opts.align_tpu,
             )
 
-        if multi and 2 * bytes_per_row * bh + fixed_bytes > vmem_budget:
+        if multi and 2 * bytes_per_row * bh + fixed_bytes > opts.vmem_budget:
             raise FusionInfeasible(
                 f"group ending at {out_ns.name}: live range exceeds VMEM budget"
             )
@@ -2148,12 +2205,10 @@ def _build_kernel_group(
         if red_grid is not None:
             grid = grid + (red_grid.steps,)
 
-        notes: Dict[str, object] = {
-            "cost_model": cost_model if kernel_streamed else "degenerate"
-        }
+        notes: Dict[str, object] = {}
         if cost is not None:
             notes["model_cycles"] = cost(bh)
-            notes["bh_priced"] = block_h is None
+            notes["bh_priced"] = opts.block_h is None
         return KernelGroup(
             stages=[plans[ns.name] for ns, _, _ in members],
             groups=groups,
@@ -2169,11 +2224,11 @@ def _build_kernel_group(
         )
 
     # -- mode selection: recompute fusion vs cross-grid-step carry -----------
-    want_rings = line_buffer is not False
+    want_rings = opts.line_buffer is not False
     # upper bound of any legal block height (plan_affine_stage's candidate
     # cap): a stage whose halo exceeds it can never carry
-    if block_h is not None:
-        bh_cap = min(block_h, e0_out)
+    if opts.block_h is not None:
+        bh_cap = min(opts.block_h, e0_out)
     else:
         bh_cap = affine_stage_bh_cap(e0_out)
     lb_capable: Tuple[str, ...] = ()
@@ -2211,7 +2266,7 @@ def _build_kernel_group(
         except FusionInfeasible:
             # carry bookkeeping cannot fit where plain recompute fusion might
             return attempt((), False)
-        if line_buffer is True:
+        if opts.line_buffer is True:
             return kg_lb
         if not kg_lb.line_buffered and not kg_lb.rings:
             return kg_lb
@@ -2224,7 +2279,7 @@ def _build_kernel_group(
             if kg_lb.notes.get("bh_priced") else None
         )
         if c_lb is None:
-            # no scheduler pricing (explicit block_h / other cost model):
+            # no scheduler pricing (explicit block_h):
             # carry is strictly less traffic and at most equal compute, so
             # prefer it and record the choice was not cost-arbitrated
             kg_lb.notes["linebuf_mode"] = "carry-unpriced"
@@ -2301,7 +2356,7 @@ def _build_kernel_group(
 
     def attempt_lane(bw: int) -> KernelGroup:
         def tag(kg: KernelGroup, reason: str) -> KernelGroup:
-            kg.notes["lane"] = "forced" if block_w is not None else "auto-vmem"
+            kg.notes["lane"] = "forced" if opts.block_w is not None else "auto-vmem"
             kg.notes["lane_carry"] = reason
             return kg
 
@@ -2328,7 +2383,7 @@ def _build_kernel_group(
                 else "nothing-to-carry"
             )
             return tag(kg_lb, reason)
-        if line_buffer is True:
+        if opts.line_buffer is True:
             return tag(kg_lb, "carried")
         # same arbitration contract as plan_no_lane: only trust cycle
         # comparisons between model-chosen block heights; prefer carry
@@ -2358,10 +2413,10 @@ def _build_kernel_group(
                 return tag(kg_rc, "recompute-cheaper")
         return tag(kg_lb, "carried")
 
-    if block_w is not None:
+    if opts.block_w is not None:
         if lane_possible:
-            bw_eff = min(block_w, e1_out)
-            if align_tpu:
+            bw_eff = min(opts.block_w, e1_out)
+            if opts.align_tpu:
                 # emission-time lane rounding: the emitted blocks themselves
                 # are 128-lane multiples (masked lane tail), not just the
                 # aligned_blocks() report
@@ -2377,7 +2432,7 @@ def _build_kernel_group(
     def overflows(kg: KernelGroup) -> bool:
         bpr, fixed = kg.ws
         return (
-            kernel_streamed and 2 * bpr * kg.bh + fixed > vmem_budget
+            kernel_streamed and 2 * bpr * kg.bh + fixed > opts.vmem_budget
         )
 
     kg_flat: Optional[KernelGroup] = None
@@ -2389,26 +2444,22 @@ def _build_kernel_group(
     if kg_flat is not None and not (lane_possible and overflows(kg_flat)):
         return kg_flat
     # even a one-row full-width panel exceeds the budget (or fusion only
-    # fits lane-blocked): tile the lane dim.  ``lane_price="greedy"`` keeps
-    # the PR 5 behavior — widest fitting block wins, first fit returned.
-    # ``"joint"`` (default) builds *every* fitting (bh, bw) pair —
-    # ``attempt_lane`` re-runs block-height selection per width, and
-    # ``model_cycles`` now scales with the lane-step count — and keeps the
+    # fits lane-blocked): tile the lane dim.  Build *every* fitting (bh, bw)
+    # pair — ``attempt_lane`` re-runs block-height selection per width, and
+    # ``model_cycles`` now scales with the lane-step count — and keep the
     # modeled-cheapest, tie-broken toward less HBM traffic then wider
     # blocks.  128-lane multiples (the wide-fetch FW of paper Eq. 2) are
     # preferred as a *pool* whenever any fits, so pricing never trades a
     # hardware-tileable width for a sub-cycle modeling difference — the
     # same budget-beats-alignment rule as plan_affine_stage.
     fitting: List[KernelGroup] = []
-    for bw_cand in lane_width_candidates(e1_out, order=lane_price):
+    for bw_cand in lane_width_candidates(e1_out):
         try:
             kg2 = attempt_lane(bw_cand)
         except FusionInfeasible:
             continue
         if overflows(kg2):
             continue
-        if lane_price == "greedy":
-            return kg2
         fitting.append(kg2)
     if fitting:
         aligned = [kg for kg in fitting if kg.bw % LANE == 0]
@@ -2418,7 +2469,6 @@ def _build_kernel_group(
             kg.hbm_bytes(),
             -kg.bw,
         ))
-        best.notes["lane_price"] = "joint"
         return best
     if kg_flat is not None:
         return kg_flat
@@ -2433,25 +2483,12 @@ def _build_kernel_group(
 
 
 def build_pipeline_plan(
-    pipe: Pipeline,
-    *,
-    block_h: Optional[int] = None,
-    block_w: Optional[int] = None,
-    lane_block: object = "auto",
-    fuse: bool = True,
-    grid_reduction: bool = True,
-    red_grid_threshold: int = RED_GRID_THRESHOLD,
-    vmem_budget: int = VMEM_BYTES,
-    cost_model: str = "scheduler",
-    align_tpu: bool = False,
-    line_buffer: object = "auto",
-    red_resident: bool = True,
-    batch: Optional[int] = None,
-    batch_capacity: Optional[int] = None,
-    red_chunk: Optional[int] = None,
-    lane_price: str = "joint",
+    pipe: Pipeline, options: PlanOptions = PlanOptions(), **overrides
 ) -> PipelinePlan:
-    """``batch=N`` plans a leading grid dim sweeping N independent tiles
+    """Plan ``pipe`` under ``options``, with any keyword of
+    :class:`PlanOptions` in ``overrides`` replacing its field.
+
+    ``batch=N`` plans a leading grid dim sweeping N independent tiles
     through one ``pallas_call`` per kernel group: every input buffer (and
     every kernel output) gains a leading batch dim, the per-tile plan —
     views, rings, scratch, block heights — is reused unchanged per batch
@@ -2462,11 +2499,9 @@ def build_pipeline_plan(
     padded slots are masked to exact zeros, so one capacity-sized compile
     serves any occupancy up to it.
 
-    ``red_chunk`` and ``lane_price`` are schedule knobs surfaced for the
-    autotuner (``backend/autotune``): the grid-reduction chunk size and
-    the budget-driven lane-width policy (``"joint"`` scheduler-priced
-    (bh, bw) selection, ``"greedy"`` the historical widest-first fit) —
-    see :func:`_build_kernel_group`."""
+    ``plan.notes`` records every option (``batch_capacity`` resolved)."""
+    opts = replace(options, **overrides)
+    batch, batch_capacity = opts.batch, opts.batch_capacity
     if batch_capacity is not None and batch is None:
         raise ValueError("batch_capacity requires batch")
     if batch is not None:
@@ -2521,22 +2556,14 @@ def build_pipeline_plan(
     assign = {n: n for n in order}               # stage -> fusion-group root
     members: Dict[str, List[str]] = {n: [n] for n in order}
 
-    build_kw = dict(
-        block_h=block_h, block_w=block_w, lane_block=lane_block,
-        vmem_budget=vmem_budget,
-        cost_model=cost_model,
-        align_tpu=align_tpu, grid_reduction=grid_reduction,
-        red_grid_threshold=red_grid_threshold,
-        line_buffer=line_buffer, red_resident=red_resident,
-        red_chunk=red_chunk, lane_price=lane_price,
-    )
+    extra: Dict[str, Any] = {}
     if params:
-        build_kw.update(params=frozenset(params), contractions=contractions)
+        extra.update(params=frozenset(params), contractions=contractions)
 
     def group_infos(root: str) -> List[Tuple]:
         return [by_name[n] for n in order if n in set(members[root])]
 
-    if fuse:
+    if opts.fuse:
         for name in reversed(device):
             cons = consumers.get(name, [])
             if not cons or name == pipe.output:
@@ -2553,7 +2580,7 @@ def build_pipeline_plan(
             try:
                 _build_kernel_group(
                     [by_name[n] for n in order if n in trial],
-                    shapes, **build_kw,
+                    shapes, opts, **extra,
                 )
             except (FusionInfeasible, UnsupportedAccessError, ValueError):
                 continue
@@ -2565,14 +2592,10 @@ def build_pipeline_plan(
     for name in order:
         if assign[name] != name or name not in members:
             continue
-        kernels.append(_build_kernel_group(group_infos(name), shapes, **build_kw))
-    notes = {
-        "fuse": fuse, "grid_reduction": grid_reduction,
-        "cost_model": cost_model, "vmem_budget": vmem_budget,
-        "align_tpu": align_tpu, "line_buffer": line_buffer,
-        "red_resident": red_resident, "block_w": block_w,
-        "red_chunk": red_chunk, "lane_price": lane_price,
-    }
+        kernels.append(
+            _build_kernel_group(group_infos(name), shapes, opts, **extra)
+        )
+    notes: Dict[str, object] = asdict(opts)
     if batch is not None:
         # the batch dim is a post-processing step over finished per-tile
         # kernel groups: fusion trials, block-height pricing, and VMEM
@@ -2584,7 +2607,6 @@ def build_pipeline_plan(
         for kg in kernels:
             kg.batch_grid = bg
             kg.grid = (batch_capacity,) + kg.grid
-        notes["batch"] = batch
         notes["batch_capacity"] = batch_capacity
     return PipelinePlan(pipe, nstages, kernels, notes=notes, params=layouts)
 
@@ -2603,6 +2625,8 @@ __all__ = [
     "Contraction",
     "ParamLayout",
     "PaddedGrid",
+    "PlanOptions",
+    "TUNABLE_KEYS",
     "KernelGroup",
     "PipelinePlan",
     "scheduler_cost",

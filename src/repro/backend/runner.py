@@ -31,7 +31,7 @@ import hashlib
 import os
 import warnings
 from collections import OrderedDict
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from functools import partial
 from pathlib import Path
 from typing import Dict, Iterable, List, Mapping, Optional
@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.ubplan import VMEM_BYTES
 from repro.frontend.lower import Pipeline, execute_pipeline, normalize_pipeline
 
 from . import tracing
@@ -50,7 +49,7 @@ from .errors import (
     LaneCarryDegradeWarning,
     TunedModeMismatchWarning,
 )
-from .plan import PipelinePlan, RED_GRID_THRESHOLD, build_pipeline_plan
+from .plan import TUNABLE_KEYS, PipelinePlan, PlanOptions, build_pipeline_plan
 from .verify import assert_plan_verified
 
 
@@ -167,12 +166,6 @@ class PallasPipeline:
         """Bytes of parameters this pipeline holds on the device."""
         return sum(int(a.nbytes) for a in self.params.values())
 
-    @property
-    def stages(self) -> List[CompiledKernel]:
-        """The emitted kernels (pre-refactor name; one kernel may now cover
-        several fused stages)."""
-        return self.kernels
-
     def stage(self, name: str) -> CompiledKernel:
         """Kernel writing buffer ``name`` (or containing the fused stage)."""
         for k in self.kernels:
@@ -182,8 +175,6 @@ class PallasPipeline:
             if name in k.stage_names:
                 return k
         raise KeyError(name)
-
-    kernel = stage
 
     def run(self, inputs: Mapping[str, np.ndarray]) -> Dict[str, jax.Array]:
         """Execute every kernel; returns all *materialized* buffers
@@ -269,48 +260,6 @@ _PIPELINE_CACHE_MAX = 128
 # a harness that clears between candidates retains its observability.
 _CACHE_STATS = {"hits": 0, "misses": 0, "evictions": 0}
 
-# the planner's own defaults, mirrored here so cache keys can be
-# normalized without running the planner.  An entry whose value equals the
-# default is dropped before hashing: ``compile_pipeline(app)`` and
-# ``compile_pipeline(app, block_w=None)`` (an explicit default) are the
-# same plan and must share one cache entry — hashing the kwargs dict
-# verbatim silently missed on exactly that drift.  Normalization also
-# keeps every historical key stable when the planner *gains* a keyword:
-# a new knob at its default vanishes from the hash input.
-_PLAN_KWARG_DEFAULTS: Dict[str, object] = dict(
-    block_h=None,
-    block_w=None,
-    lane_block="auto",
-    fuse=True,
-    grid_reduction=True,
-    red_grid_threshold=RED_GRID_THRESHOLD,
-    vmem_budget=VMEM_BYTES,
-    cost_model="scheduler",
-    align_tpu=False,
-    line_buffer="auto",
-    red_resident=True,
-    batch=None,
-    batch_capacity=None,
-    red_chunk=None,
-    lane_price="joint",
-)
-
-# the knobs a stored schedule (backend/autotune) may override: the search
-# axes of the autotuner.  Everything else — budgets, batching, alignment —
-# is part of the *problem*, not the schedule, and keys the schedule db.
-TUNABLE_KEYS = frozenset(
-    {"block_h", "block_w", "line_buffer", "red_chunk", "fuse", "lane_price"}
-)
-
-
-def _normalize_plan_kwargs(plan_kwargs: Mapping) -> Dict[str, object]:
-    """Drop default-valued entries (see ``_PLAN_KWARG_DEFAULTS``)."""
-    return {
-        k: v
-        for k, v in plan_kwargs.items()
-        if not (k in _PLAN_KWARG_DEFAULTS and v == _PLAN_KWARG_DEFAULTS[k])
-    }
-
 
 def _hash_pipeline_content(h, pipe: Pipeline) -> None:
     """Feed the lowered pipeline's content — every normalized stage
@@ -334,41 +283,35 @@ def _hash_pipeline_content(h, pipe: Pipeline) -> None:
     h.update(b"elem:f32")
 
 
-def plan_cache_key(pipe: Pipeline, mode: str, plan_kwargs: Mapping) -> str:
+def plan_cache_key(pipe: Pipeline, mode: str, options: PlanOptions) -> str:
     """Content hash identifying a compiled pipeline: the *inputs* of
     planning — the lowered pipeline content (see
-    ``_hash_pipeline_content``) — plus every plan-affecting keyword and
-    the resolved execution mode.  Keywords are normalized against the
-    planner defaults first (default-valued entries are dropped), so an
-    explicitly passed default and an omitted keyword hash identically.
+    ``_hash_pipeline_content``) — plus every plan option away from its
+    default (:meth:`PlanOptions.items`, so an explicit default and an
+    omitted option hash identically) and the resolved execution mode.
     Two pipelines with identical lowered content and parameters share one
-    cache entry; changing any extent, expression, non-default plan knob,
-    or the mode produces a different key.  Planning itself is *not* run
-    to compute the key, which is what lets a cache hit skip re-planning
+    cache entry; changing any extent, expression, non-default option, or
+    the mode produces a different key.  Planning itself is *not* run to
+    compute the key, which is what lets a cache hit skip re-planning
     entirely."""
     h = hashlib.sha256()
     h.update(mode.encode())
-    norm = _normalize_plan_kwargs(plan_kwargs)
-    h.update(repr(sorted(norm.items(), key=lambda kv: kv[0])).encode())
+    h.update(repr(options.items()).encode())
     _hash_pipeline_content(h, pipe)
     return h.hexdigest()
 
 
-def schedule_db_key(pipe: Pipeline, plan_kwargs: Mapping = ()) -> str:
+def schedule_db_key(pipe: Pipeline, options: PlanOptions = PlanOptions()) -> str:
     """Key a pipeline into the autotuner's schedule database: the same
-    content hash as :func:`plan_cache_key` minus the *tunable* keywords
+    content hash as :func:`plan_cache_key` minus the *tunable* options
     (``TUNABLE_KEYS`` — the schedule itself) and minus the execution
     mode.  Two compiles that pose the same planning problem — identical
     lowered content, budget, batching — look up the same stored schedule
-    regardless of which schedule knobs or mode they currently run with."""
-    fixed = {
-        k: v for k, v in dict(plan_kwargs).items() if k not in TUNABLE_KEYS
-    }
+    regardless of which schedule options or mode they currently run
+    with."""
     h = hashlib.sha256()
     h.update(b"schedule-db:")
-    h.update(repr(sorted(
-        _normalize_plan_kwargs(fixed).items(), key=lambda kv: kv[0]
-    )).encode())
+    h.update(repr(options.heuristic().items()).encode())
     _hash_pipeline_content(h, pipe)
     return h.hexdigest()
 
@@ -430,50 +373,24 @@ def enable_compile_cache() -> str:
 def compile_pipeline(
     pipe: Pipeline,
     *,
-    interpret: Optional[bool] = None,
     mode: str = "interpret",
     cache: bool = False,
-    block_h: Optional[int] = None,
-    block_w: Optional[int] = None,
-    lane_block: object = "auto",
-    fuse: bool = True,
-    grid_reduction: bool = True,
-    red_grid_threshold: int = RED_GRID_THRESHOLD,
-    vmem_budget: int = VMEM_BYTES,
-    cost_model: str = "scheduler",
-    align_tpu: bool = False,
-    line_buffer: object = "auto",
-    red_resident: bool = True,
-    batch: Optional[int] = None,
-    batch_capacity: Optional[int] = None,
-    red_chunk: Optional[int] = None,
-    lane_price: str = "joint",
     verify: object = "auto",
     tune: object = False,
+    **options,
 ) -> PallasPipeline:
-    """``line_buffer`` picks the recompute-vs-carry mode for fused
-    intermediates and shifted input deliveries: ``False`` restores the
-    recompute-fusion scheme (one view per tap, panels re-evaluated per
-    shift), ``True`` forces cross-grid-step rings wherever structurally
-    feasible, ``"auto"`` (default) lets the scheduler cost model choose per
-    chain.  ``red_resident`` keeps small reduction-invariant operands whole
-    in VMEM under grid reductions instead of refetching chunks per row
-    panel.  ``block_w`` forces 2-D lane-blocked grids (see
-    ``plan.build_pipeline_plan``).
+    """Plan, verify and emit ``pipe``; ``options`` are the keywords of
+    :class:`~repro.backend.plan.PlanOptions` (an unknown one raises
+    ``TypeError``).
 
     ``mode`` is the execution switch (``"interpret"`` | ``"compiled"`` |
-    ``"auto"``); the legacy ``interpret`` boolean, when given, overrides it.
-    A compiled pipeline always plans with ``align_tpu``.
+    ``"auto"``).  A compiled pipeline always plans with ``align_tpu``.
     ``cache=True`` consults the plan-keyed pipeline cache: a hit returns
     the previously compiled :class:`PallasPipeline` (its jit-warmed kernels
-    included) without re-planning or re-emitting.
-
-    ``batch=N`` plans a leading batch grid dim sweeping N independent
-    tiles per invocation (``batch_capacity`` sizes the grid in slots for
-    ragged final batches; see ``plan.build_pipeline_plan``).  Both are
-    plan kwargs and therefore part of the cache key: a batched and an
-    unbatched compile of the same pipeline — or two different capacities —
-    can never collide on one cache entry.
+    included) without re-planning or re-emitting.  Every option, batching
+    included, is part of the cache key: a batched and an unbatched compile
+    of the same pipeline — or two different capacities — can never
+    collide on one cache entry.
 
     ``verify`` gates static plan certification (``backend.verify``): every
     freshly built plan is checked before emission and a violation raises
@@ -488,9 +405,9 @@ def compile_pipeline(
     (``backend/autotune``) before planning: ``"auto"`` (or ``True``) looks
     up the default on-disk db, a path string/`ScheduleDB` uses that db,
     ``False`` (default) skips the lookup.  A stored winning schedule
-    overrides only the tunable knobs the caller left at their defaults —
+    overrides only the tunable options the caller left at their defaults —
     an explicit ``block_h=...`` always beats the db — and the overridden
-    kwargs *do* enter the plan cache key, so tuned and heuristic compiles
+    options *do* enter the plan cache key, so tuned and heuristic compiles
     of one pipeline never collide on a cache entry.  A miss (no stored
     schedule for this pipeline) falls back to the heuristic planner
     silently; a hit whose stored row was *measured* in a different
@@ -502,35 +419,18 @@ def compile_pipeline(
     lane-blocked kernel (halo wider than the lane block, carry
     bookkeeping over budget, ...) emits a :class:`LaneCarryDegradeWarning`
     naming the planner's reason instead of degrading silently."""
-    if interpret is not None:
-        mode = "interpret" if interpret else "compiled"
     mode = resolve_mode(mode)
-    # Mosaic refuses blocks whose last two dims are not (8, 128)-tileable,
-    # so a compiled pipeline always plans aligned tiles
-    align_tpu = align_tpu or mode == "compiled"
-    plan_kwargs = dict(
-        block_h=block_h,
-        block_w=block_w,
-        lane_block=lane_block,
-        fuse=fuse,
-        grid_reduction=grid_reduction,
-        red_grid_threshold=red_grid_threshold,
-        vmem_budget=vmem_budget,
-        cost_model=cost_model,
-        align_tpu=align_tpu,
-        line_buffer=line_buffer,
-        red_resident=red_resident,
-        batch=batch,
-        batch_capacity=batch_capacity,
-        red_chunk=red_chunk,
-        lane_price=lane_price,
-    )
+    opts = PlanOptions(**options)
+    if mode == "compiled":
+        # Mosaic refuses blocks whose last two dims are not
+        # (8, 128)-tileable, so a compiled pipeline always plans aligned
+        opts = replace(opts, align_tpu=True)
     if verify not in (True, False, "auto"):
         raise ValueError(f"verify must be True, False, or 'auto': {verify!r}")
     if tune is not False and tune is not None:
         from .autotune import lookup_schedule_entry
 
-        entry = lookup_schedule_entry(pipe, plan_kwargs, db=tune)
+        entry = lookup_schedule_entry(pipe, opts, db=tune)
         if entry:
             stored_mode = entry.get("mode")
             if stored_mode is not None and stored_mode != mode:
@@ -542,15 +442,10 @@ def compile_pipeline(
                     TunedModeMismatchWarning,
                     stacklevel=2,
                 )
-            for k, v in entry.get("schedule", {}).items():
-                if (
-                    k in TUNABLE_KEYS
-                    and plan_kwargs[k] == _PLAN_KWARG_DEFAULTS[k]
-                ):
-                    plan_kwargs[k] = v
+            opts = opts.tuned(entry.get("schedule", {}))
     key: Optional[str] = None
     if cache:
-        key = plan_cache_key(pipe, mode, plan_kwargs)
+        key = plan_cache_key(pipe, mode, opts)
         hit = _PIPELINE_CACHE.get(key)
         if hit is not None:
             _CACHE_STATS["hits"] += 1
@@ -559,8 +454,8 @@ def compile_pipeline(
                 assert_plan_verified(hit.plan)
             return hit
         _CACHE_STATS["misses"] += 1
-    plan = build_pipeline_plan(pipe, **plan_kwargs)
-    if plan_kwargs.get("line_buffer") is True:
+    plan = build_pipeline_plan(pipe, options=opts)
+    if opts.line_buffer is True:
         _warn_lane_carry_degrades(plan)
     if verify is not False:
         assert_plan_verified(plan)
@@ -575,8 +470,7 @@ def compile_pipeline(
     for kg in plan.kernels:
         try:
             kernels.append(emit_kernel(
-                kg, mode=mode, vmem_budget=plan.notes["vmem_budget"],
-                params=params,
+                kg, mode=mode, vmem_budget=opts.vmem_budget, params=params,
             ))
         except Exception as e:
             # a certified plan failing to lower is an emitter (or Pallas)

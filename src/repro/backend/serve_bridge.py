@@ -1,18 +1,17 @@
 """Continuous-batching serve bridge for compiled batched pipelines.
 
-``serve.engine.ServeEngine`` serves token-decode requests through a fixed
-number of batch slots: requests pack into slots, the ragged tail is padded
-with filler requests whose results are discarded.  This module applies the
-same slot discipline to *pipeline tiles*: a :class:`PipelineServer` owns one
+Requests pack into a fixed number of batch slots, and the ragged tail is
+padded with filler requests whose results are discarded
+(:func:`pad_to_slots`).  A :class:`PipelineServer` owns one
 pipeline compiled at full slot capacity (``batch = batch_capacity =
 batch_slots``, so every service step reuses the same cached kernels — the
-batch kwargs are part of the plan cache key), queues :class:`TileRequest`\\ s,
+batch options are part of the plan cache key), queues :class:`TileRequest`\\ s,
 and each ``step()`` packs up to ``batch_slots`` pending tiles into a single
 batched dispatch: one ``pallas_call`` grid sweep per kernel group instead of
 one call per tile.
 
 Raggedness is handled by the serve layer, not the kernel: a short final
-batch is padded to capacity with zero tiles via ``serve.engine.pad_to_slots``
+batch is padded to capacity with zero tiles via :func:`pad_to_slots`
 and the filler slots' outputs are discarded, which keeps the valid slots'
 emission identical to the unbatched path (see ``_StageCtx.panel_mask`` on
 why an in-kernel batch mask would break bit-exactness).
@@ -48,7 +47,7 @@ in one request can corrupt another's result.
   advance time deterministically.
 * **Retry-with-recompile.**  A dispatch that *raises* climbs a recovery
   ladder: drop the (possibly poisoned) plan-cache entry and recompile
-  fresh; then recompile on the heuristic schedule (tunable kwargs
+  fresh; then recompile on the heuristic schedule (tunable options
   stripped, ``tune=False``); each recovered rung emits a
   :class:`DegradedModeWarning`.  Only when the ladder is exhausted does
   the batch enter quarantine.
@@ -87,7 +86,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.frontend.lower import Pipeline
-from repro.serve.engine import pad_to_slots
 
 from . import tracing
 from .errors import (
@@ -100,8 +98,8 @@ from .errors import (
     QueueFullError,
     RequestError,
 )
+from .plan import TUNABLE_KEYS
 from .runner import (
-    TUNABLE_KEYS,
     PallasPipeline,
     compile_pipeline,
     drop_pipeline_cache_entry,
@@ -118,6 +116,19 @@ from .runner import (
 # int8, int16, bool, float16, float32) and is widened there; int32, int64
 # and float64 batches are cast to float32 on the host (runner.stage_dtype)
 _NUMERIC_KINDS = frozenset("fiub")
+
+
+def pad_to_slots(
+    requests: List, slots: int, make_filler: Callable[[], object]
+) -> List:
+    """Pad a ragged request list up to ``slots`` with filler requests
+    (pad-and-discard: fillers do the slot's work on dummy data and their
+    results are thrown away)."""
+    if len(requests) > slots:
+        raise ValueError(
+            f"{len(requests)} requests exceed the {slots} batch slots"
+        )
+    return list(requests) + [make_filler() for _ in range(slots - len(requests))]
 
 
 @jax.jit
@@ -239,8 +250,6 @@ class PipelineServer:
         self.dispatches = 0
         # dispatches whose inputs crossed narrower than float32
         self.narrow_dispatches = 0
-        # dispatches whose per-slot finite flags the device computed
-        self.finite_on_device_dispatches = 0
         # host<->device traffic of every dispatch, filler slots included
         self.bytes_to_device = 0
         self.bytes_from_device = 0
@@ -444,7 +453,6 @@ class PipelineServer:
         # too), launched before the copy-back so the device reduces while
         # the host copies
         finite = ub_finite_slots(tuple(bufs[ck.name] for ck in pp.kernels))
-        self.finite_on_device_dispatches += 1
         # one host conversion per kernel per dispatch — slicing per slot on
         # the jax array would pay a separate device sync per tile
         with tracing.span(tracing.COPY_BACK):
@@ -485,14 +493,14 @@ class PipelineServer:
         """Recovery-ladder recompile: drop the (possibly poisoned) cache
         entry first so the fresh compile can never be handed the broken
         pipeline back as a cache hit.  ``heuristic=True`` strips every
-        tunable kwarg and disables the schedule db — the most conservative
-        plan the heuristic planner produces for this problem."""
+        tunable plan option and disables the schedule db — the most
+        conservative plan the heuristic planner produces for this
+        problem."""
         pipe, pp, ckw = self._table[key]
         drop_pipeline_cache_entry(pp.cache_key)
         kw = dict(ckw)
         if heuristic:
-            for k in TUNABLE_KEYS:
-                kw.pop(k, None)
+            kw = {k: v for k, v in kw.items() if k not in TUNABLE_KEYS}
             kw["tune"] = False
         self.fault_counters["recompiles"] += 1
         with tracing.span(tracing.RECOMPILE):
@@ -685,7 +693,6 @@ class PipelineServer:
             "failed": self.failed,
             "dispatches": self.dispatches,
             "narrow_dispatches": self.narrow_dispatches,
-            "finite_on_device_dispatches": self.finite_on_device_dispatches,
             "bytes_to_device": self.bytes_to_device,
             "bytes_from_device": self.bytes_from_device,
             # parameters held on the device (uploaded at registration,
@@ -699,4 +706,4 @@ class PipelineServer:
         }
 
 
-__all__ = ["TileRequest", "PipelineServer", "ub_finite_slots"]
+__all__ = ["TileRequest", "PipelineServer", "pad_to_slots", "ub_finite_slots"]

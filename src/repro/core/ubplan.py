@@ -169,37 +169,20 @@ def plan_affine_stage(
     return min(fitting, key=lambda bh: (cost(bh), waste(bh), -bh))
 
 
-def lane_width_candidates(lane_extent: int, *, order: str = "greedy") -> List[int]:
-    """Candidate lane-block widths for a 2-D (row x lane) grid.
-
-    ``order="greedy"`` (default) is the original engagement list, widest
+def lane_width_candidates(lane_extent: int) -> List[int]:
+    """Candidate lane-block widths for a 2-D (row x lane) grid, widest
     first: every multiple of the 128-lane vector width below the extent
     (the wide-fetch FW of paper Eq. 2 — a lane block is a whole number of
-    wide fetches), then power-of-two fallbacks (all < 128, so the two
-    pools are disjoint) as the escape hatch of last resort.  Because the
-    128-multiples lead, budget-driven engagement naturally lands on a
-    lane-tileable width whenever one fits, and falls through to narrower
-    blocks only to honour the VMEM guarantee — the same
-    budget-beats-alignment rule as :func:`plan_affine_stage`.
-
-    ``order="joint"`` is the candidate *pool* for joint (bh, bw) pricing
-    (``backend/plan``'s scheduler-model lane selection and the autotuner):
-    a superset of the greedy list that also yields the ceil-division
-    widths ``ceil(extent / s)`` for small step counts ``s`` — the
-    low-padding splits a narrow extent actually wants, which the
-    128-multiple/power-of-two-only list cannot express (e.g. extent 96
-    gains 48 and 32-adjacent 24, extent 300 gains 150/100/75...).  Still
-    sorted widest first so greedy consumers of the pool stay monotone.
+    wide fetches), the power-of-two widths below 128, and the ceil-division
+    widths ``ceil(extent / s)`` for step counts ``s`` of 2 to 8 — the
+    low-padding splits a narrow extent wants (extent 300 gains
+    150/100/75...).  This is the pool the joint (bh, bw) pricing of
+    ``backend/plan`` and the autotuner choose from.
 
     Widths >= the extent are excluded — they are the degenerate "full
     width resident" plan the lane grid exists to avoid."""
-    mults = list(range((lane_extent - 1) // LANE * LANE, 0, -LANE))
-    small = [w for w in (64, 32, 16, 8, 4, 2, 1) if w < lane_extent]
-    if order == "greedy":
-        return (mults + small) or [1]
-    if order != "joint":
-        raise ValueError(f"order must be 'greedy' or 'joint': {order!r}")
-    pool = set(mults) | set(small)
+    pool = set(range((lane_extent - 1) // LANE * LANE, 0, -LANE))
+    pool |= {w for w in (64, 32, 16, 8, 4, 2, 1) if w < lane_extent}
     for s in range(2, 9):
         w = -(-lane_extent // s)
         if 0 < w < lane_extent:

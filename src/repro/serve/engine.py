@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.backend.serve_bridge import pad_to_slots
 from repro.models import decode_step, init_kv_cache
 from repro.models.config import ModelConfig
 
@@ -78,19 +79,6 @@ class Request:
     max_new: int = 16
     generated: List[int] = field(default_factory=list)
     done: bool = False
-
-
-def pad_to_slots(requests: List, slots: int, make_filler: Callable[[], object]) -> List:
-    """Pad a ragged request list up to the engine's fixed slot count with
-    filler requests (pad-and-discard: fillers do the slot's work on dummy
-    data and their results are thrown away).  Shared by ``ServeEngine``
-    (decode slots) and ``backend.serve_bridge.PipelineServer`` (batched
-    pipeline slots)."""
-    if len(requests) > slots:
-        raise ValueError(
-            f"{len(requests)} requests exceed the {slots} batch slots"
-        )
-    return list(requests) + [make_filler() for _ in range(slots - len(requests))]
 
 
 class ServeEngine:

@@ -8,6 +8,7 @@ Run standalone with ``python -m pytest -q -m tune`` (scripts/ci.sh --tune).
 from __future__ import annotations
 
 import json
+import os
 
 import jax
 import numpy as np
@@ -22,6 +23,7 @@ from repro.backend.autotune import (
     lookup_schedule_entry,
     search,
 )
+from repro.backend.plan import PlanOptions
 from repro.backend.runner import TUNABLE_KEYS, schedule_db_key
 
 pytestmark = pytest.mark.tune
@@ -127,7 +129,7 @@ def test_schedule_db_roundtrip_into_compile_pipeline(tmp_path):
 
     reloaded = ScheduleDB.load(dbp)
     assert reloaded.lookup(r.key) == r.schedule
-    assert lookup_schedule(app.pipeline, {}, db=dbp) == r.schedule
+    assert lookup_schedule(app.pipeline, db=dbp) == r.schedule
 
     clear_pipeline_cache(reset_stats=True)
     tuned = compile_pipeline(app.pipeline, cache=True, tune=dbp)
@@ -148,7 +150,7 @@ def test_stored_schedule_applies_and_caller_overrides_win(tmp_path):
     stored block_h plans, an explicit caller kwarg beats the db, and a
     db miss (different pipeline content) falls back to the heuristic."""
     app = make_app("gaussian", size=18)
-    key = schedule_db_key(app.pipeline, {})
+    key = schedule_db_key(app.pipeline)
     db = ScheduleDB(path=str(tmp_path / "db.json"))
     db.store(key, {
         "app": "gaussian", "schedule": {"block_h": 2}, "warm_us": 1.0,
@@ -163,7 +165,7 @@ def test_stored_schedule_applies_and_caller_overrides_win(tmp_path):
     explicit = compile_pipeline(app.pipeline, tune=db, block_h=5)
     assert explicit.kernels[0].bh == 5           # caller beats the db
     other = make_app("gaussian", size=20)        # different content: db miss
-    assert lookup_schedule(other.pipeline, {}, db=db) is None
+    assert lookup_schedule(other.pipeline, db=db) is None
     heur = compile_pipeline(other.pipeline, tune=db)
     assert heur.kernels[0].bh != 2 or True       # heuristic planned
 
@@ -184,7 +186,7 @@ def test_interpret_measured_winner_warns_into_compiled_mode(tmp_path):
     app = make_app("gaussian", size=18)
     # compiled mode always plans TPU-aligned tiles, so the interpret run
     # that can stand in for it is an align_tpu=True one: same db key
-    aligned = {"align_tpu": True}
+    aligned = PlanOptions(align_tpu=True)
     key = schedule_db_key(app.pipeline, aligned)
     db = ScheduleDB(path=str(tmp_path / "db.json"))
     db.store(key, {
@@ -208,6 +210,28 @@ def test_interpret_measured_winner_warns_into_compiled_mode(tmp_path):
         inputs = {"input": np.zeros((18, 18), np.float32)}
         with pytest.raises(RuntimeError, match="TPU"):
             pp(inputs)
+
+
+# the apps and sizes benchmarks/tune_bench.py tunes: the committed
+# schedule_db.json holds one winner row for each
+COMMITTED_DB = os.path.join(os.path.dirname(__file__), "..", "schedule_db.json")
+COMMITTED_ROWS = {
+    "harris": {"schedule": "sch3", "size": 20},
+    "unsharp": {"size": 18},
+    "matmul": {"m": 16, "n": 16, "k": 2048},
+}
+
+
+@pytest.mark.parametrize("name", sorted(COMMITTED_ROWS))
+def test_committed_schedule_db_rows_are_found(name):
+    """The schedule-db key of each tuned app still finds its committed
+    row: a change to the key's inputs would orphan every stored winner
+    and silently fall back to the heuristic planner."""
+    app = make_app(name, **COMMITTED_ROWS[name])
+    entry = lookup_schedule_entry(app.pipeline, db=COMMITTED_DB)
+    assert entry is not None and entry["app"] == name
+    stored = json.loads(open(COMMITTED_DB).read())["entries"]
+    assert stored[schedule_db_key(app.pipeline)] == entry
 
 
 def test_tuned_numerics_match_heuristic(tmp_path):

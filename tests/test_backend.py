@@ -8,6 +8,7 @@ import pytest
 
 from repro.apps.paper_apps import make_app
 from repro.backend import (
+    FusionInfeasible,
     build_pipeline_plan,
     compile_pipeline,
     max_abs_error,
@@ -684,63 +685,71 @@ def test_grid_reduction_masked_tail_with_padded_rows():
 def test_lane_width_candidates():
     """Widest-first lane candidates: every 128-multiple below the extent
     leads (so budget-driven engagement lands lane-tileable whenever one
-    fits), then power-of-two escape hatches; never the full extent (that's
-    the flat plan)."""
+    fits), then the narrower widths; never the full extent (that's the
+    flat plan)."""
     from repro.core.ubplan import lane_width_candidates
 
     c = lane_width_candidates(2046)
-    assert c[0] == 1920 and all(w % 128 == 0 for w in c[: c.index(64)])
+    assert c[0] == 1920 and all(w % 128 == 0 for w in c[: c.index(1023)])
     assert all(w < 2046 for w in c)
     assert sorted(set(c), reverse=True) == c           # strictly descending
-    assert lane_width_candidates(300)[:2] == [256, 128]
-    # small extents: only the fallbacks exist
-    assert lane_width_candidates(100) == [64, 32, 16, 8, 4, 2, 1]
+    assert lane_width_candidates(300)[:2] == [256, 150]
+    # small extents: no 128-multiple exists
+    assert max(lane_width_candidates(100)) == 64
     assert lane_width_candidates(1) == [1]
 
 
 def test_lane_width_candidates_joint_order():
-    """order="joint" (the joint (bh, bw) pricer's pool) is a descending
-    superset of the greedy list that adds the low-padding ceil-division
-    widths a narrow extent wants; order="greedy" stays the exact PR 5
-    list, pinning the historical first-fit engagement decisions."""
+    """The candidate pool of the joint (bh, bw) pricer: the 128-multiples,
+    the power-of-two widths below 128, and the low-padding ceil-division
+    widths a narrow extent wants, in descending order."""
     from repro.core.ubplan import lane_width_candidates
 
-    greedy = lane_width_candidates(300)
-    joint = lane_width_candidates(300, order="joint")
-    assert lane_width_candidates(300, order="greedy") == greedy
-    assert set(greedy) <= set(joint)
+    joint = lane_width_candidates(300)
+    assert {256, 128, 64, 32, 16, 8, 4, 2, 1} <= set(joint)
     assert sorted(set(joint), reverse=True) == joint    # still descending
     # ceil-division splits: ceil(300/2)=150, /3=100, /4=75 — none of which
     # the 128-multiple / power-of-two pools can express
     assert {150, 100, 75} <= set(joint)
     assert all(w < 300 for w in joint)
-    # sub-128 widths exist for narrow extents in both orders
-    assert {50, 34, 64} <= set(lane_width_candidates(100, order="joint"))
-    assert lane_width_candidates(1, order="joint") == [1]
-    with pytest.raises(ValueError, match="order"):
-        lane_width_candidates(300, order="widest")
+    # sub-128 widths exist for narrow extents
+    assert {50, 34, 64} <= set(lane_width_candidates(100))
+    assert lane_width_candidates(1) == [1]
 
 
 def test_joint_lane_pricing_beats_or_matches_greedy():
     """Budget-driven lane engagement prices every fitting (bh, bw) pair
     with the scheduler model (model_cycles scales with the lane-step
-    count) and keeps the modeled-cheapest — never worse than the greedy
-    widest-first fit, and still budget-clean and bit-exact.  The greedy
-    policy stays available behind lane_price="greedy"."""
+    count) and keeps the modeled-cheapest: no fitting candidate width,
+    forced with block_w, prices lower; the plan stays budget-clean and
+    bit-exact."""
+    from repro.core.ubplan import lane_width_candidates
+
     app = make_app("unsharp", size=18)
     budget = 1024
-    greedy = build_pipeline_plan(
-        app.pipeline, vmem_budget=budget, lane_price="greedy"
-    )
     joint = build_pipeline_plan(app.pipeline, vmem_budget=budget)
-    kj, kg_ = joint.kernels[0], greedy.kernels[0]
-    assert kj.lane_grid is not None and kg_.lane_grid is not None
-    assert kj.vmem_bytes <= budget and kg_.vmem_bytes <= budget
-    assert kj.notes["lane_price"] == "joint"
-    assert "lane_price" not in kg_.notes
+    kj = joint.kernels[0]
+    assert kj.lane_grid is not None
+    assert kj.vmem_bytes <= budget
+    assert kj.notes["lane"] == "auto-vmem"
     cj = kj.notes["model_cycles"]
-    cg = kg_.notes["model_cycles"]
-    assert cj <= cg, (kj.bw, cj, kg_.bw, cg)
+    e1 = kj.stages[-1].nstage.pure_extents[-1]
+    priced = 0
+    for bw in lane_width_candidates(e1):
+        if kj.bw % 128 == 0 and bw % 128:
+            continue            # 128-multiples form the pool when one fits
+        try:
+            forced = build_pipeline_plan(
+                app.pipeline, vmem_budget=budget, block_w=bw
+            ).kernels[0]
+        except FusionInfeasible:
+            continue
+        bpr, fixed = forced.ws
+        if 2 * bpr * forced.bh + fixed > budget:
+            continue
+        priced += 1
+        assert cj <= forced.notes["model_cycles"], (kj.bw, cj, bw)
+    assert priced >= 2
     pp = compile_pipeline(app.pipeline, vmem_budget=budget)
     errs = max_abs_error(pp, _inputs(app))
     assert max(errs.values()) <= TOL, errs

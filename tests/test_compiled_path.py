@@ -19,6 +19,7 @@ else runs everywhere.
 """
 
 import time
+from dataclasses import fields
 
 import jax
 import numpy as np
@@ -26,6 +27,8 @@ import pytest
 
 from repro.apps.paper_apps import make_app
 from repro.backend import (
+    PipelineServer,
+    PlanOptions,
     clear_pipeline_cache,
     compile_pipeline,
     pipeline_cache_size,
@@ -81,13 +84,15 @@ def test_compiled_mode_on_cpu_raises_clearly():
     error instead of silently falling back to the interpreter."""
     app = make_app("gaussian", size=18)
     inputs = _inputs(app)
-    # the legacy boolean spells the same request
-    for kw in ({"mode": "compiled"}, {"interpret": False}):
-        pp = compile_pipeline(app.pipeline, **kw)
-        assert pp.mode == "compiled"
-        assert all(ck.mode == "compiled" for ck in pp.kernels)
-        with pytest.raises(RuntimeError, match="TPU jax backend"):
-            pp(inputs)
+    pp = compile_pipeline(app.pipeline, mode="compiled")
+    assert pp.mode == "compiled"
+    assert all(ck.mode == "compiled" for ck in pp.kernels)
+    with pytest.raises(RuntimeError, match="TPU jax backend"):
+        pp(inputs)
+    # ``mode`` is the one execution switch: a name that is no plan option
+    # is refused
+    with pytest.raises(TypeError, match="interpret"):
+        compile_pipeline(app.pipeline, interpret=False)
 
 
 def test_compiled_mode_plans_tpu_aligned_tiles():
@@ -107,7 +112,7 @@ def test_compiled_mode_plans_tpu_aligned_tiles():
         )
         assert again is pp
         assert pp.cache_key == plan_cache_key(
-            app.pipeline, "compiled", {"align_tpu": True}
+            app.pipeline, "compiled", PlanOptions(align_tpu=True)
         )
     finally:
         clear_pipeline_cache(reset_stats=True)
@@ -200,46 +205,91 @@ def test_pipeline_cache_hit_and_key_contract():
 
 
 def test_plan_cache_key_is_deterministic_and_content_keyed():
-    kwargs = dict(block_h=None, fuse=True)
+    opts = PlanOptions(block_h=None, fuse=True)
     a1 = make_app("gaussian", size=18)
     a2 = make_app("gaussian", size=18)
     a3 = make_app("gaussian", size=20)
-    k1 = plan_cache_key(a1.pipeline, "interpret", kwargs)
-    assert k1 == plan_cache_key(a1.pipeline, "interpret", kwargs)
-    assert k1 == plan_cache_key(a2.pipeline, "interpret", kwargs)
-    assert k1 != plan_cache_key(a3.pipeline, "interpret", kwargs)
-    assert k1 != plan_cache_key(a1.pipeline, "compiled", kwargs)
-    assert k1 != plan_cache_key(a1.pipeline, "interpret", dict(kwargs, block_h=4))
-
-
-def test_plan_cache_key_normalizes_default_kwargs():
-    """The key-drift bugfix: kwargs are normalized against the planner
-    defaults before hashing, so an explicitly passed default and an
-    omitted keyword produce one key — compile_pipeline(app) and
-    compile_pipeline(app, block_w=None) share a single cache entry
-    instead of silently missing.  Non-default values still miss."""
-    app = make_app("gaussian", size=18)
-    k_bare = plan_cache_key(app.pipeline, "interpret", {})
-    assert k_bare == plan_cache_key(
-        app.pipeline, "interpret", dict(block_w=None)
+    k1 = plan_cache_key(a1.pipeline, "interpret", opts)
+    assert k1 == plan_cache_key(a1.pipeline, "interpret", opts)
+    assert k1 == plan_cache_key(a2.pipeline, "interpret", opts)
+    assert k1 != plan_cache_key(a3.pipeline, "interpret", opts)
+    assert k1 != plan_cache_key(a1.pipeline, "compiled", opts)
+    assert k1 != plan_cache_key(
+        a1.pipeline, "interpret", PlanOptions(block_h=4)
     )
-    # the full default kwargs dict compile_pipeline builds hashes the same
-    from repro.backend.runner import _PLAN_KWARG_DEFAULTS
 
+
+# a value away from the default for every plan option
+NON_DEFAULT = dict(
+    block_h=4, block_w=4, lane_block=False, fuse=False, grid_reduction=False,
+    red_grid_threshold=512, vmem_budget=1 << 20, align_tpu=True,
+    line_buffer=True, red_resident=False, batch=2, batch_capacity=4,
+    red_chunk=64,
+)
+
+
+@pytest.mark.parametrize("option", [f.name for f in fields(PlanOptions)])
+def test_plan_cache_key_normalizes_default_kwargs(option):
+    """Options are normalized against their defaults before hashing, so an
+    explicitly passed default and an omitted option produce one key —
+    compile_pipeline(app) and compile_pipeline(app, block_w=None) share a
+    single cache entry instead of silently missing.  A non-default value
+    still misses."""
+    app = make_app("gaussian", size=18)
+    default = PlanOptions.__dataclass_fields__[option].default
+    k_bare = plan_cache_key(app.pipeline, "interpret", PlanOptions())
     assert k_bare == plan_cache_key(
-        app.pipeline, "interpret", dict(_PLAN_KWARG_DEFAULTS)
+        app.pipeline, "interpret", PlanOptions(**{option: default})
     )
     assert k_bare != plan_cache_key(
-        app.pipeline, "interpret", dict(block_w=4)
+        app.pipeline, "interpret", PlanOptions(**{option: NON_DEFAULT[option]})
     )
 
     clear_pipeline_cache(reset_stats=True)
     try:
         pp1 = compile_pipeline(app.pipeline, cache=True)
-        pp2 = compile_pipeline(app.pipeline, cache=True, block_w=None)
+        pp2 = compile_pipeline(app.pipeline, cache=True, **{option: default})
         assert pp2 is pp1 and pipeline_cache_size() == 1
         stats = pipeline_cache_stats()
         assert stats["misses"] == 1 and stats["hits"] == 1
+    finally:
+        clear_pipeline_cache(reset_stats=True)
+
+
+# the plan cache key of each benchmark cell's served compile
+# (chipbench/configs/<cell>.json: make_app arguments and batch_slots),
+# recorded before PlanOptions replaced the keyword dicts: the same key
+# names the same plan, so a change here changes what the cells run
+SERVED_KEYS = {
+    "blur_1080p": (
+        "gaussian", dict(size=1082, width=1922), 4,
+        "61300c92d983b1b3be3aaefc61de109261dd420dbd8732e74508e93b9e81fc85",
+    ),
+    "camera_isp_1080": (
+        "camera", dict(size=540), 4,
+        "b177e708eb53d35adcea61051d432861dd91eedd29206d924f6762a033ac538a",
+    ),
+    "resnet50_conv2x_block": (
+        "resnet50_block",
+        dict(img=56, cin=256, mid=64, weight_seed=1512, act_scale=0.015625),
+        8,
+        "cc24fea2b20c978973e75b147a4adc9dc42acc0b78da19aa035f911de843f2b1",
+    ),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(SERVED_KEYS))
+def test_served_cell_plan_cache_keys_are_stable(cell):
+    """A compiled-mode server plans with align_tpu and batch = capacity =
+    batch_slots; its cache key is the recorded one."""
+    name, kw, slots, want = SERVED_KEYS[cell]
+    app = make_app(name, **kw)
+    opts = PlanOptions(align_tpu=True, batch=slots, batch_capacity=slots)
+    assert plan_cache_key(app.pipeline, "compiled", opts) == want
+    clear_pipeline_cache(reset_stats=True)
+    try:
+        srv = PipelineServer(app.pipeline, batch_slots=slots, mode="compiled")
+        assert srv.pipeline.cache_key == want
     finally:
         clear_pipeline_cache(reset_stats=True)
 

@@ -429,12 +429,12 @@ def test_schedule_db_corruption_degrades_and_round_trips(tmp_path, mode):
     res = autotune_search(
         app.pipeline, label="g13", db=path, measure=False
     )
-    assert lookup_schedule(app.pipeline, {}, db=path) == res.schedule
+    assert lookup_schedule(app.pipeline, db=path) == res.schedule
     ins = sweep_inputs(app, SWEEP_SEED)
     out = app.pipeline.output
     with corrupt_schedule_db(path, mode):
         with pytest.warns(ScheduleDBCorruptWarning):
-            assert lookup_schedule(app.pipeline, {}, db=path) is None
+            assert lookup_schedule(app.pipeline, db=path) is None
         with pytest.warns(ScheduleDBCorruptWarning, match="heuristic"):
             pp = compile_pipeline(app.pipeline, tune=path)
         heur = compile_pipeline(app.pipeline)
@@ -443,7 +443,7 @@ def test_schedule_db_corruption_degrades_and_round_trips(tmp_path, mode):
         )
     with warnings.catch_warnings():           # restored file: no warning
         warnings.simplefilter("error", ScheduleDBCorruptWarning)
-        assert lookup_schedule(app.pipeline, {}, db=path) == res.schedule
+        assert lookup_schedule(app.pipeline, db=path) == res.schedule
         compile_pipeline(app.pipeline, tune=path)
 
 
@@ -466,7 +466,7 @@ def test_truncated_db_on_disk_round_trip(tmp_path):
         res = autotune_search(
             app.pipeline, label="g13", db=path, measure=False
         )
-    assert lookup_schedule(app.pipeline, {}, db=path) == res.schedule
+    assert lookup_schedule(app.pipeline, db=path) == res.schedule
 
 
 def test_malformed_rows_degrade_by_name(tmp_path):
@@ -474,7 +474,7 @@ def test_malformed_rows_degrade_by_name(tmp_path):
     heuristic miss with the reason in the warning — a future writer's rows
     never poison this reader's compile."""
     app = make_app("gaussian", size=13)
-    key = schedule_db_key(app.pipeline, {})
+    key = schedule_db_key(app.pipeline)
     for row, reason in [
         ({"schedule": {"block_h": 4}, "row_version": 99}, "row_version"),
         ({"schedule": {"warp_speed": 9}}, "non-tunable"),
@@ -484,7 +484,7 @@ def test_malformed_rows_degrade_by_name(tmp_path):
         path = str(tmp_path / f"db_{reason[:4].strip()}.json")
         ScheduleDB(path=path, entries={key: row}).save()
         with pytest.warns(ScheduleDBCorruptWarning, match=reason):
-            assert lookup_schedule(app.pipeline, {}, db=path) is None
+            assert lookup_schedule(app.pipeline, db=path) is None
 
 
 # ---------------------------------------------------------------------------
@@ -510,7 +510,7 @@ def test_warning_stacklevels_point_at_caller(tmp_path):
         f.write("not json")
 
     with pytest.warns(ScheduleDBCorruptWarning) as rec:
-        lookup_schedule(app.pipeline, {}, db=bad)       # stacklevel=3 chain
+        lookup_schedule(app.pipeline, db=bad)       # stacklevel=3 chain
     assert all(
         os.path.basename(w.filename) == me
         for w in _only(rec, ScheduleDBCorruptWarning)
@@ -527,7 +527,7 @@ def test_warning_stacklevels_point_at_caller(tmp_path):
     ScheduleDB(
         path=tuned,
         entries={
-            schedule_db_key(app.pipeline, {}): {
+            schedule_db_key(app.pipeline): {
                 "schedule": {}, "mode": "compiled",
             }
         },

@@ -2,9 +2,9 @@
 
 ``PipelineServer`` packs queued tiles into full-capacity batched dispatches
 (one ``pallas_call`` sweep per kernel group per batch) and pads the ragged
-tail with zero tiles it discards — the same pad-and-discard slot discipline
-``ServeEngine`` applies to decode requests, shared via
-``serve.engine.pad_to_slots``.  These tests pin the bridge's contract: slot
+tail with zero tiles it discards (``serve_bridge.pad_to_slots``, the
+pad-and-discard slot discipline ``ServeEngine`` also applies to decode
+requests).  These tests pin the bridge's contract: slot
 packing and drain order, bit-exactness of every served tile against the
 per-tile loop (ragged final dispatch included), request validation, and the
 cache/dispatch observability counters the serve benchmark reports.
@@ -22,7 +22,7 @@ from repro.backend import (
     compile_pipeline,
     pipeline_cache_stats,
 )
-from repro.serve.engine import pad_to_slots
+from repro.backend.serve_bridge import pad_to_slots
 
 pytestmark = pytest.mark.serve
 
