@@ -117,7 +117,9 @@ def pin_host_allocator(dispatch_bytes: int) -> bool:
     """Keep two dispatches' host buffers of ``dispatch_bytes`` each on
     glibc's heap: pin the mmap threshold at 32 MiB and the trim threshold
     at twice ``dispatch_bytes`` (at least 64 MiB), neither below what glibc
-    would set itself.  The trim threshold only rises.  It is process-wide:
+    would set itself.  Two cover what a pipelined server holds at once:
+    the head batch's copied-back outputs beside the next batch's staged
+    inputs and the host buffers of its copy, already started.  The trim threshold only rises.  It is process-wide:
     from then on glibc no longer moves either threshold, so every block up
     to 32 MiB comes from the heap and the process keeps up to the trim
     threshold of freed heap.  Returns whether glibc holds the pin (False

@@ -58,6 +58,18 @@ in one request can corrupt another's result.
   :class:`PoisonedTileError`, while every healthy tile completes from a
   clean dispatch and is therefore bit-exact vs the per-tile pipeline.
 
+Pipelining: a dispatch is a launch (stage, kernels, finite flags, and the
+start of every device→host copy) and a finish (the copy-back).  When a
+full same-shape batch is queued behind the head batch, ``step()``
+launches it before it finishes the head, so the device runs batch n+1
+while the host copies batch n back.  The launched batch stays in the
+queue until the next ``step()`` pops it and finishes that launch; if the
+batch it pops is not exactly those requests (one expired meanwhile), the
+launch is discarded and the batch dispatched afresh.  A fault raised by
+such a launch is held and charged to its own batch, in the next step.
+No batch launches ahead where a request of it or of the head batch
+carries a deadline, nor from a recovery-ladder or quarantine dispatch.
+
 ``stats()`` reports the serving counters, the per-fault-class counters,
 and the process-wide pipeline-cache counters in one dict.
 """
@@ -65,6 +77,7 @@ and the process-wide pipeline-cache counters in one dict.
 from __future__ import annotations
 
 import functools
+import itertools
 import time
 import warnings
 from collections import deque
@@ -165,6 +178,26 @@ class TileRequest:
         return self.done and self.error is None
 
 
+@dataclass
+class _Launch:
+    """A launched dispatch: every kernel's output (device arrays, or the
+    host arrays a fault injector put in their place) with its copy to the
+    host started, the per-slot finite flags, and the bytes it moves."""
+
+    bufs: Dict[str, Union[jax.Array, np.ndarray]]
+    finite: jax.Array
+    nbytes: int                       # staged inputs + outputs to copy back
+
+
+@dataclass
+class _Ahead:
+    """The batch launched ahead of its step: its requests (still queued)
+    and its launch, or the exception the launch raised."""
+
+    reqs: List[TileRequest]
+    launch: Union[_Launch, Exception]
+
+
 def _fault_counter_zeros() -> Dict[str, int]:
     return {
         "validation_rejects": 0,       # submit() refused the request
@@ -253,6 +286,11 @@ class PipelineServer:
         # host<->device traffic of every dispatch, filler slots included
         self.bytes_to_device = 0
         self.bytes_from_device = 0
+        # batches launched before their step (a launch that raised
+        # included), and those of them thrown away unfinished
+        self.dispatches_ahead = 0
+        self.ahead_discarded = 0
+        self._ahead: Optional[_Ahead] = None
         self.fault_counters: Dict[str, int] = _fault_counter_zeros()
 
     # -- request lifecycle --------------------------------------------------
@@ -419,13 +457,15 @@ class PipelineServer:
         dispatches without touching kernel code."""
         return pp.run(ins)
 
-    def _dispatch(
+    def _launch(
         self, pipe: Pipeline, pp: PallasPipeline, reqs: List[TileRequest]
-    ) -> Tuple[Dict[str, np.ndarray], jax.Array]:
-        """One padded-to-capacity batched execution; returns per-kernel
-        stacked host arrays and the device's per-slot finite flags
-        (:func:`ub_finite_slots`).  Raises whatever the kernels raise —
-        fault handling is the caller's (``_service``) job."""
+    ) -> _Launch:
+        """Launch one padded-to-capacity batched execution: stage, run the
+        kernels, launch the device's per-slot finite flags
+        (:func:`ub_finite_slots`) and start every device→host copy, so
+        the host waits on none of it until :meth:`_finish`.  Raises
+        whatever the kernels raise — fault handling is the caller's
+        (``_service``) job."""
         with tracing.span(tracing.STACK):
             dtypes = {
                 n: stage_dtype([np.asarray(r.inputs[n]).dtype for r in reqs])
@@ -445,26 +485,67 @@ class PipelineServer:
             }
         staged = sum(a.nbytes for a in ins.values())
         self.bytes_to_device += staged
-        bufs = self._run_pipeline(pp, ins)
+        ran = self._run_pipeline(pp, ins)
         self.dispatches += 1
         if any(a.dtype != np.float32 for a in ins.values()):
             self.narrow_dispatches += 1
+        bufs = {ck.name: ran[ck.name] for ck in pp.kernels}
         # flagged on what the seam returned (the fault injectors' arrays
-        # too), launched before the copy-back so the device reduces while
-        # the host copies
-        finite = ub_finite_slots(tuple(bufs[ck.name] for ck in pp.kernels))
+        # too)
+        finite = ub_finite_slots(tuple(bufs.values()))
+        copied = sum(a.nbytes for a in bufs.values())
+        self.bytes_from_device += copied
+        for a in (*bufs.values(), finite):
+            if isinstance(a, jax.Array):
+                a.copy_to_host_async()
+        return _Launch(bufs, finite, staged + copied)
+
+    def _finish(
+        self, launch: _Launch
+    ) -> Tuple[Dict[str, np.ndarray], jax.Array]:
+        """Wait out a launch's copies; returns per-kernel stacked host
+        arrays and the per-slot finite flags."""
         # one host conversion per kernel per dispatch — slicing per slot on
         # the jax array would pay a separate device sync per tile
         with tracing.span(tracing.COPY_BACK):
-            outs = {
-                ck.name: np.asarray(bufs[ck.name])
-                for ck in pp.kernels
-            }
-        copied = sum(a.nbytes for a in outs.values())
-        self.bytes_from_device += copied
+            outs = {name: np.asarray(a) for name, a in launch.bufs.items()}
         # keep this dispatch's host buffers on the heap for the next one
-        pin_host_allocator(staged + copied)
-        return outs, finite
+        pin_host_allocator(launch.nbytes)
+        return outs, launch.finite
+
+    def _dispatch(
+        self, pipe: Pipeline, pp: PallasPipeline, reqs: List[TileRequest]
+    ) -> Tuple[Dict[str, np.ndarray], jax.Array]:
+        """One batched execution, launched and finished."""
+        return self._finish(self._launch(pipe, pp, reqs))
+
+    def _launch_ahead(self, head: List[TileRequest]) -> None:
+        """Launch the next batch while the head batch's copies run, where
+        the queue holds a full same-shape batch behind the head and no
+        request of either batch carries a deadline: the ahead launch's
+        time would count against the head batch's late-completion check
+        (an injected clock, as ``faults.slow_dispatch`` advances, would
+        charge it there outright).  A launch that raises is held, to be
+        raised in its own batch's step."""
+        if len(self.pending) < self.batch_slots:
+            return
+        batch = list(itertools.islice(self.pending, self.batch_slots))
+        key = batch[0][0]
+        reqs = [r for _k, r in batch]
+        if any(k != key for k, _r in batch) or any(
+            r.deadline is not None for r in head + reqs
+        ):
+            return
+        pipe, pp, _kw = self._table[key]
+        self.dispatches_ahead += 1
+        with tracing.span(tracing.AHEAD):
+            try:
+                launch: Union[_Launch, Exception] = self._launch(
+                    pipe, pp, reqs
+                )
+            except Exception as e:
+                launch = e
+        self._ahead = _Ahead(reqs, launch)
 
     @staticmethod
     def _poisoned_slots(finite: jax.Array, n_live: int) -> List[int]:
@@ -575,16 +656,34 @@ class PipelineServer:
                 return name, first
         return next(iter(outs)), ()
 
-    def _service(self, key: Tuple, reqs: List[TileRequest]) -> None:
+    def _service(
+        self,
+        key: Tuple,
+        reqs: List[TileRequest],
+        launch: Union[_Launch, Exception, None] = None,
+    ) -> None:
         """Service one same-shape batch with the full recovery ladder:
         dispatch → (on raise) recompile fresh → recompile heuristic →
         quarantine bisection.  On return every request in ``reqs`` is
-        ``done`` — completed or failed closed with a named error."""
+        ``done`` — completed or failed closed with a named error.
+        ``launch`` is the batch's launch made ahead in the previous step
+        (or what it raised); ``None`` launches it here.  Between the
+        batch's launch and its finish the next batch may launch ahead
+        (:meth:`_launch_ahead`); a ladder rung never does."""
         pipe, pp, _kw = self._table[key]
         done: Optional[Tuple[Dict[str, np.ndarray], jax.Array]] = None
         try:
-            done = self._dispatch(pipe, pp, reqs)
+            if isinstance(launch, Exception):
+                raise launch
+            if launch is None:
+                launch = self._launch(pipe, pp, reqs)
+            self._launch_ahead(reqs)
+            done = self._finish(launch)
         except Exception as first_err:
+            # the next batch is dispatched afresh, after the recompiles
+            if self._ahead is not None:
+                self._ahead = None
+                self.ahead_discarded += 1
             self.fault_counters["dispatch_failures"] += 1
             for heuristic in (False, True):
                 try:
@@ -641,11 +740,19 @@ class PipelineServer:
         queue is empty).  One dispatch serves one shape: the longest
         consecutive same-shape run at the head of the queue (up to
         ``batch_slots``), so mixed-shape traffic completes in submission
-        order."""
-        with tracing.span(tracing.STEP, dispatch=self.dispatches):
+        order.
+
+        The batch may have been launched by the previous step; this step
+        then finishes that launch (see the module docstring), and
+        ``dispatch`` on its span numbers the batch's own launch."""
+        ahead, self._ahead = self._ahead, None
+        launched = ahead is not None and isinstance(ahead.launch, _Launch)
+        with tracing.span(tracing.STEP, dispatch=self.dispatches - launched):
             now = self._clock()
             finished: List[TileRequest] = list(self._expire(now))
             if not self.pending:
+                if ahead is not None:
+                    self.ahead_discarded += 1
                 return finished
             key = self.pending[0][0]
             reqs: List[TileRequest] = []
@@ -655,7 +762,15 @@ class PipelineServer:
                 and self.pending[0][0] == key
             ):
                 reqs.append(self.pending.popleft()[1])
-            self._service(key, reqs)
+            launch = None
+            if ahead is not None:
+                if len(ahead.reqs) == len(reqs) and all(
+                    a is r for a, r in zip(ahead.reqs, reqs)
+                ):
+                    launch = ahead.launch
+                else:
+                    self.ahead_discarded += 1
+            self._service(key, reqs, launch)
             # completed-late check: a request whose deadline passed during the
             # dispatch fails closed — its computed outputs are discarded, not
             # returned late as if on time
@@ -695,6 +810,8 @@ class PipelineServer:
             "narrow_dispatches": self.narrow_dispatches,
             "bytes_to_device": self.bytes_to_device,
             "bytes_from_device": self.bytes_from_device,
+            "dispatches_ahead": self.dispatches_ahead,
+            "ahead_discarded": self.ahead_discarded,
             # parameters held on the device (uploaded at registration,
             # never per dispatch; bytes_to_device counts requests alone)
             "param_bytes": sum(held.values()),

@@ -16,6 +16,7 @@ STEP = "ub.step"                  # PipelineServer.step; argument dispatch=n
 STACK = "ub.stack"                # pad to slots + np.stack at the staged dtype
 TO_DEVICE = "ub.to_device"        # PallasPipeline.run: inputs onto the device
 KERNEL = "ub.kernel."             # + kernel name: one host-side launch
+AHEAD = "ub.ahead"                # launch of the next batch before the head's copy-back
 COPY_BACK = "ub.copy_back"        # np.asarray of every kernel's output
 FINITE_CHECK = "ub.finite_check"  # read of the device's per-slot NaN/Inf flags
 RECOMPILE = "ub.recompile"        # recovery-ladder recompile
