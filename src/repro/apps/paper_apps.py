@@ -23,7 +23,7 @@ from typing import Callable, Dict, List, Tuple
 
 import numpy as np
 
-from repro.frontend.expr import Const, IterVal, Select, maximum, minimum
+from repro.frontend.expr import Const, IterVal, Select, exp, maximum, minimum
 from repro.frontend.func import Func, RDom, Var
 from repro.frontend.lower import Pipeline, lower_pipeline
 
@@ -453,16 +453,193 @@ def build_resnet50_block(
 
 
 # ---------------------------------------------------------------------------
+# efficientnet_b0_mbconv — MBConv6 block with squeeze-and-excitation
+# ---------------------------------------------------------------------------
+
+
+def sigmoid(e):
+    """``1 / (1 + exp(-e))``, built from ``exp``."""
+    return Const(1) / (1 + exp(0 - e))
+
+
+def swish(e):
+    """``e * sigmoid(e)`` (SiLU), built from ``exp``."""
+    return e * sigmoid(e)
+
+
+def efficientnet_mbconv_weights(
+    cin: int, expand: int, se_ratio: float, kernel: int, seed: int
+):
+    """The block's folded weights and biases, drawn in this order from
+    ``numpy.random.default_rng(seed)``: He-normal ``w1 (cin, mid)``, bias
+    ``b1 (mid,)``, depthwise ``w2 (kernel, kernel, mid)`` (indexed ky, kx,
+    channel; fan-in ``kernel**2``), ``b2 (mid,)``, the squeeze's ``wr (mid,
+    sq)``, ``br (sq,)``, the excitation's ``we (sq, mid)``, ``be (mid,)``,
+    and ``w3 (mid, cin)``, ``b3 (cin,)``, where ``mid = cin * expand`` and
+    ``sq = max(1, int(cin * se_ratio))``; biases are normal draws of
+    standard deviation 0.1.  Each is rounded to float32."""
+    mid, sq = cin * expand, max(1, int(cin * se_ratio))
+    rng = np.random.default_rng(seed)
+
+    def he(shape, fan_in):
+        return rng.standard_normal(shape) * np.sqrt(2.0 / fan_in)
+
+    def bias(n):
+        return 0.1 * rng.standard_normal(n)
+
+    out = (
+        he((cin, mid), cin), bias(mid),
+        he((kernel, kernel, mid), kernel * kernel), bias(mid),
+        he((mid, sq), mid), bias(sq),
+        he((sq, mid), sq), bias(mid),
+        he((mid, cin), mid), bias(cin),
+    )
+    return tuple(a.astype(np.float32) for a in out)
+
+
+def build_efficientnet_b0_mbconv(
+    img: int = 56, cin: int = 24, expand: int = 6, se_ratio: float = 0.25,
+    kernel: int = 3, weight_seed: int = 0, act_scale: float = 1 / 32,
+) -> AppBundle:
+    """An identity MBConv block of EfficientNet-B0 (Tan & Le 2019, Table
+    1) with squeeze-and-excitation and batch norm folded into weights and
+    biases; at the defaults, the second block of the stage "MBConv6, k3x3,
+    24 channels" at 56 x 56:
+
+        x  = act_scale * (code - 128)
+        h1 = swish(conv1x1(x; cin -> mid) + b1)        zero ring ('same')
+        h2 = swish(dwconv(h1; kernel x kernel, stride 1) + b2)
+        s  = mean over the image of h2                  (mid,)
+        g  = sigmoid(we . swish(wr . s + br) + be)      (mid,)
+        y  = conv1x1(h2 * g; mid -> cin) + b3 + x       linear, identity skip
+
+    with ``mid = cin * expand`` and ``sq = max(1, int(cin * se_ratio))``
+    squeeze channels (``se_ratio=0``: no squeeze-and-excitation, ``g =
+    1``).  The request ``ifmap`` holds uint8 codes ``(cin,
+    img + 2p, img + 2p)`` with a ring of ``p = kernel // 2`` don't-care
+    pixels, zero point 128; the ring is read only where ``h1`` is zero, its
+    padding.  The weights (:func:`efficientnet_mbconv_weights`) are
+    parameters.  The output is float32 ``(cin, img, img)``."""
+    if kernel % 2 != 1:
+        raise ValueError(f"kernel must be odd: {kernel}")
+    mid, sq, pad = cin * expand, max(1, int(cin * se_ratio)), kernel // 2
+    w1, b1, w2, b2, wr, br, we, be, w3, b3 = efficientnet_mbconv_weights(
+        cin, expand, se_ratio, kernel, weight_seed
+    )
+    m = Var("m")
+    inp = Func.input("ifmap", 3)                 # [x, y, c]
+    params = [Func.param(*kv) for kv in (
+        ("w1", w1), ("b1", b1), ("w2", w2), ("b2", b2), ("wr", wr),
+        ("br", br), ("we", we), ("be", be), ("w3", w3), ("b3", b3),
+    )]
+    p_w1, p_b1, p_w2, p_b2, p_wr, p_br, p_we, p_be, p_w3, p_b3 = params
+
+    x0 = Func("x0")                              # (c, y, x): channels first
+    x0[x, y, co] = (inp[x, y, co] - 128) * act_scale
+    rc = RDom(cin, name="c")
+    c1 = Func("expand")                          # (y, x, m): channels last
+    c1[m, x, y] = 0
+    c1.update((m, x, y), c1[m, x, y] + x0[x, y, rc[0]] * p_w1[m, rc[0]], rc)
+    edge = img + 2 * pad
+    inside = (
+        (IterVal("x") > pad - 1) * (IterVal("x") < edge - pad)
+        * (IterVal("y") > pad - 1) * (IterVal("y") < edge - pad)
+    )
+    h1 = Func("h1")                              # zero on its ring
+    h1[m, x, y] = Select(inside, swish(c1[m, x, y] + p_b1[m]), Const(0))
+
+    k = RDom(kernel, kernel, name="k")           # (kx, ky)
+    c2 = Func("depthwise")
+    c2[m, x, y] = 0
+    c2.update(
+        (m, x, y),
+        c2[m, x, y] + h1[m, x + k[0], y + k[1]] * p_w2[m, k[0], k[1]],
+        k,
+    )
+    h2 = Func("h2")
+    h2[m, x, y] = swish(c2[m, x, y] + p_b2[m])
+
+    hg, se = h2, []
+    if se_ratio > 0:
+        hg, se = _squeeze_excite(h2, img, mid, sq, p_wr, p_br, p_we, p_be)
+    else:
+        params = [p_w1, p_b1, p_w2, p_b2, p_w3, p_b3]
+    rn = RDom(mid, name="n")
+    c3 = Func("project")                         # (co, y, x): channels first
+    c3[x, y, co] = 0
+    c3.update((x, y, co), c3[x, y, co] + hg[rn[0], x, y] * p_w3[co, rn[0]], rn)
+    out = Func("efficientnet_b0_mbconv")
+    out[x, y, co] = c3[x, y, co] + p_b3[co] + x0[x + pad, y + pad, co]
+
+    stages = [x0, c1, h1, c2, h2] + se + [c3]
+    for f in stages:
+        f.store_root()
+    out.hw_accelerate()
+    funcs = [inp, *params, *stages, out]
+    pipe = lower_pipeline(out, funcs, {"x": img, "y": img, "co": cin})
+    return AppBundle(
+        "efficientnet_b0_mbconv", "dnn", pipe, funcs, out,
+        {"x": img, "y": img, "co": cin},
+        {"ifmap": (cin, edge, edge)},
+        description="EfficientNet-B0 MBConv6 block with squeeze-and-"
+                    "excitation, weights on the device",
+    )
+
+
+def _squeeze_excite(h2, img, mid, sq, p_wr, p_br, p_we, p_be):
+    """The squeeze-and-excitation of ``h2`` (channels ``m``): the gated
+    func and the stages it adds."""
+    m, j = Var("m"), Var("j")
+    r = RDom(img, img, name="p")                 # every pixel
+    sq_f = Func("squeeze")
+    sq_f[m] = 0
+    sq_f.update((m,), sq_f[m] + h2[m, r[0], r[1]] * (1.0 / (img * img)), r)
+    rm = RDom(mid, name="q")
+    fr = Func("reduce")
+    fr[j] = 0
+    fr.update((j,), fr[j] + sq_f[rm[0]] * p_wr[j, rm[0]], rm)
+    hr = Func("hr")
+    hr[j] = swish(fr[j] + p_br[j])
+    rj = RDom(sq, name="e")
+    fe = Func("excite")
+    fe[m] = 0
+    fe.update((m,), fe[m] + hr[rj[0]] * p_we[m, rj[0]], rj)
+    gate = Func("gate")
+    gate[m] = sigmoid(fe[m] + p_be[m])
+    hg = Func("gated")
+    hg[m, x, y] = h2[m, x, y] * gate[m]
+    return hg, [sq_f, fr, hr, fe, gate, hg]
+
+
+# ---------------------------------------------------------------------------
 # mobilenet — depthwise-separable convolution layer (DNN pipeline)
 # ---------------------------------------------------------------------------
 
 
 def build_mobilenet(
-    img: int = 16, cin: int = 8, cout: int = 8, tiles: int = 4
+    img: int = 16, cin: int = 8, cout: int = 8, tiles: int = 4,
+    weights=None,
 ) -> AppBundle:
+    """``weights`` (``(dw_weights (cin, 3, 3), pw_weights (cout, cin))``,
+    loop order) binds the weights as parameters held by the compiled
+    pipeline, so each request carries only ``ifmap`` and the pointwise
+    stage runs as an MXU contraction; without it they arrive with every
+    request."""
     inp = Func.input("ifmap", 3)      # [c, x, y] — channel fastest
-    wdw = Func.input("dw_weights", 3)  # [kx, ky, c]
-    wpw = Func.input("pw_weights", 2)  # [c, co]
+    input_extents = {"ifmap": (img + 2, img + 2, cin)}   # loop order (y, x, c)
+    if weights is None:
+        wdw = Func.input("dw_weights", 3)  # [kx, ky, c]
+        wpw = Func.input("pw_weights", 2)  # [c, co]
+        input_extents.update(dw_weights=(cin, 3, 3), pw_weights=(cout, cin))
+    else:
+        dw_w, pw_w = weights
+        if np.shape(dw_w) != (cin, 3, 3) or np.shape(pw_w) != (cout, cin):
+            raise ValueError(
+                f"weights of shapes {np.shape(dw_w)}, {np.shape(pw_w)} != "
+                f"{(cin, 3, 3)}, {(cout, cin)}"
+            )
+        wdw = Func.param("dw_weights", dw_w)
+        wpw = Func.param("pw_weights", pw_w)
 
     rs = RDom(3, 3, name="s")          # spatial reduction (depthwise)
     sx, sy = rs[0], rs[1]
@@ -494,11 +671,7 @@ def build_mobilenet(
     return AppBundle(
         "mobilenet", "dnn", pipe, funcs, pw,
         {"co": cout, "x": img, "y": img},
-        {
-            "ifmap": (img + 2, img + 2, cin),   # loop order (y, x, c)
-            "dw_weights": (cin, 3, 3),
-            "pw_weights": (cout, cin),
-        },
+        input_extents,
         tile_count=tiles,
         description="layer using separable, multi-channel convolution",
     )
@@ -535,7 +708,7 @@ def build_matmul(m: int = 32, n: int = 32, k: int = 32) -> AppBundle:
 # ---------------------------------------------------------------------------
 ALL_APPS = ["gaussian", "harris", "upsample", "unsharp", "camera", "resnet", "mobilenet"]
 # additional backend workloads, not part of the paper's Table III set
-EXTRA_APPS = ["matmul", "resnet50_block"]
+EXTRA_APPS = ["matmul", "resnet50_block", "efficientnet_b0_mbconv"]
 
 
 def make_app(name: str, **kw) -> AppBundle:
@@ -549,12 +722,14 @@ def make_app(name: str, **kw) -> AppBundle:
         "mobilenet": build_mobilenet,
         "matmul": build_matmul,
         "resnet50_block": build_resnet50_block,
+        "efficientnet_b0_mbconv": build_efficientnet_b0_mbconv,
     }
     return builders[name](**kw)
 
 
 __all__ = [
     "AppBundle", "ALL_APPS", "EXTRA_APPS", "make_app", "resnet50_block_weights",
+    "efficientnet_mbconv_weights", "sigmoid", "swish",
 ] + [
     f"build_{n}" for n in ALL_APPS + EXTRA_APPS
 ]

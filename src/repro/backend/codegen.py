@@ -42,7 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.ubplan import LANE, KernelPlan
-from repro.frontend.expr import BinOp, Const, Expr, FuncRef, IterVal, Select
+from repro.frontend.expr import BinOp, Const, Expr, FuncRef, IterVal, Select, UnOp
 from repro.frontend.lower import NormalizedStage
 
 from . import tracing
@@ -283,8 +283,13 @@ def _tap(
     """Extract one load's value lattice (:func:`_tap_raw`) and align it
     with the stage's output block (transpose + broadcast axes)."""
     tap, tags = _tap_raw(ctx, refs, scratch, load_idx, rho, shift, lshift)
-    # order the kept pure-dim axes as the stage's dims, leaving unit axes
-    # in place, then reshape to the broadcastable block shape
+    return _aligned(ctx, tap, tags)
+
+
+def _aligned(ctx: _StageCtx, tap, tags: List[Optional[str]]):
+    """``tap`` (axes tagged by ``tags``) aligned with the stage's block:
+    its kept pure-dim axes ordered as the stage's dims, unit axes left in
+    place, then reshaped to the broadcastable block shape."""
     pos = [t for t, d in enumerate(tags) if d is not None]
     want = sorted(pos, key=lambda t: ctx.pure_pos[tags[t]])
     if want != pos:
@@ -307,7 +312,7 @@ def _tap_raw(
     rho: Mapping[str, int],
     shift: int,
     lshift: int = 0,
-    keep: Optional[str] = None,
+    keep: Tuple[str, ...] = (),
 ) -> Tuple[jax.Array, List[Optional[str]]]:
     """One load's value lattice — from a delivered view block, a
     cross-grid-step ring (input delivery or line-buffered intermediate), or
@@ -318,9 +323,9 @@ def _tap_raw(
     index (applied to the loaded block): strided spans and the traced
     global reduction position of a resident operand are read through the
     ref, every other offset slices the loaded value.  A tag is the pure
-    dim of a kept axis, None for a kept unit axis, or ``keep``: the one
-    reduction dim whose axis is kept whole (a contraction's channel dim,
-    absent from ``rho``)."""
+    dim of a kept axis, None for a kept unit axis, or one of ``keep``: the
+    reduction dims whose axes are kept whole (a contraction's channel dim,
+    a block reduction's dims), absent from ``rho``."""
     sp = ctx.sp
     la = sp.accesses[load_idx]
     ref_idx: List[object] = [slice(None)] * len(la.axes)
@@ -335,10 +340,11 @@ def _tap_raw(
     def plain(j: int, ax, base: int, unit: bool = False) -> None:
         """An axis no grid dim tiles: the kept reduction axis, a pure-dim
         span, a kept unit axis (``unit``), or a squeezed static index."""
-        if keep is not None and keep in dict(ax.red_coeffs):
+        kept = [d for d in keep if d in dict(ax.red_coeffs)]
+        if kept:
             k0 = ax.const - base
-            idx.append(slice(k0, k0 + ctx.nstage.extent(keep)))
-            tags.append(keep)
+            idx.append(slice(k0, k0 + ctx.nstage.extent(kept[0])))
+            tags.append(kept[0])
         elif ax.pure_dim is not None:
             span(j, ax.offset_at(rho) - base, ctx.extent(ax.pure_dim),
                  ax.stride, ax.pure_dim)
@@ -394,12 +400,8 @@ def _tap_raw(
             elif ctx.lane and j == last:
                 idx.append(lane_sl)                 # the lane-blocked dim
                 tags.append(ax.pure_dim)
-            elif ax.pure_dim is not None:
-                # scratch axes are zero-based
-                span(j, ax.offset_at(rho), ctx.extent(ax.pure_dim),
-                     ax.stride, ax.pure_dim)
             else:
-                idx.append(ax.offset_at(rho))       # squeezed static index
+                plain(j, ax, 0)                     # scratch axes are zero-based
     else:
         j0 = sp.blocked_axis_of[load_idx]
         jL = sp.lane_axis_of[load_idx] if sp.lane_axis_of else None
@@ -497,14 +499,22 @@ def _contract(
     """One tap of a :class:`~repro.backend.plan.Contraction`: the
     activation's lattice with its channel axis kept, times the
     parameter's ``(K, N)`` matrix at the tap, as one MXU matrix product in
-    float32 at float32 operand precision, ordered as the stage's block."""
+    float32 at float32 operand precision, ordered as the stage's block.
+    An activation with no axis besides the channels (a vector) is one unit
+    row, ``(1, K) x (K, N)``: Mosaic's matmul needs a non-contracting dim
+    on each side."""
     cn = ctx.sp.contraction
-    a, at = _tap_raw(ctx, refs, scratch, cn.lhs, rho, shift, lshift, cn.dim)
-    w, wt = _tap_raw(ctx, refs, scratch, cn.rhs, rho, shift, lshift, cn.dim)
+    a, at = _tap_raw(ctx, refs, scratch, cn.lhs, rho, shift, lshift, (cn.dim,))
+    w, wt = _tap_raw(ctx, refs, scratch, cn.rhs, rho, shift, lshift, (cn.dim,))
+    ka, vector = at.index(cn.dim), a.ndim == 1
+    if vector:
+        a, ka = a[None], 1
     prod = jax.lax.dot_general(
-        a, w, (((at.index(cn.dim),), (wt.index(cn.dim),)), ((), ())),
+        a, w, (((ka,), (wt.index(cn.dim),)), ((), ())),
         precision=CONTRACT_PRECISION, preferred_element_type=jnp.float32,
     )
+    if vector:
+        prod = prod[0]
     tags = [t for t in at if t != cn.dim] + [t for t in wt if t != cn.dim]
     perm = sorted(range(len(tags)), key=lambda t: ctx.pure_pos[tags[t]])
     if perm != list(range(len(tags))):
@@ -569,6 +579,11 @@ def _emit(
         if e.op == "gt":
             return jnp.where(jnp.asarray(a) > b, 1.0, 0.0)
         raise UnsupportedAccessError(f"binop {e.op} not supported by codegen")
+    if isinstance(e, UnOp):
+        a = _emit(e.a, ctx, refs, scratch, rho, shift, counter, lshift)
+        if e.op == "exp":
+            return jnp.exp(a)
+        raise UnsupportedAccessError(f"unary op {e.op} not supported by codegen")
     if isinstance(e, Select):
         c = _emit(e.cond, ctx, refs, scratch, rho, shift, counter, lshift)
         t = _emit(e.if_true, ctx, refs, scratch, rho, shift, counter, lshift)
@@ -605,6 +620,21 @@ def _stage_panel(
         for combo in itertools.product(*(range(ns.extent(d)) for d in cn.taps)):
             rho = dict(zip(cn.taps, combo))
             acc = acc + _contract(ctx, refs, scratch, rho, shift, lshift)
+    elif ctx.sp.block_reduce is not None:
+        # one sum over the member's reduced axes, scaled once
+        br = ctx.sp.block_reduce
+        acc = _emit(ns.init, ctx, refs, scratch, {}, shift, [0], lshift)
+        v, tags = _tap_raw(
+            ctx, refs, scratch, br.load, {}, shift, lshift, ns.red_dims
+        )
+        v = jnp.sum(v, axis=tuple(
+            j for j, t in enumerate(tags) if t in ns.red_dims
+        ))
+        if br.scale != 1.0:
+            v = v * br.scale
+        acc = acc + _aligned(
+            ctx, v, [t for t in tags if t not in ns.red_dims]
+        )
     elif ns.red_dims:
         acc = _emit(ns.init, ctx, refs, scratch, {}, shift, [0], lshift)
         acc = jnp.broadcast_to(

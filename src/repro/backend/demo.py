@@ -48,6 +48,7 @@ DEMO_APPS: List[Tuple[str, Dict]] = [
     ("mobilenet", {"img": 8, "cin": 4, "cout": 4}),
     ("matmul", {"m": 32, "n": 32, "k": 16}),
     ("matmul_bigk", {"m": 16, "n": 16, "k": 2048}),
+    ("efficientnet_b0_mbconv", {"img": 6, "cin": 8}),
 ]
 
 SMOKE_APPS = ["gaussian", "unsharp", "matmul", "matmul_bigk"]
@@ -185,6 +186,10 @@ def run_demo(
                 "streams": sum(len(ck.groups) + 1 for ck in pp.kernels),
                 "linebuf": "+".join(lb_stages) if lb_stages else "-",
                 "rings": pp.plan.n_rings,
+                # contraction / block_reduce / unrolled stages (plan notes)
+                "reductions": "/".join(
+                    str(n) for n in pp.plan.notes["reductions"].values()
+                ),
                 "eval_rows": pp.plan.total_eval_rows(),
                 "vmem_kib": sum(ck.plan.vmem_bytes for ck in pp.kernels) // 1024,
                 "hbm_kib": pp.plan.hbm_bytes() // 1024,
@@ -227,8 +232,9 @@ def main(argv=None) -> int:
     rows = run_demo(names, smoke=args.smoke, fuse=not args.no_fuse,
                     mode=args.mode, verify=args.verify)
     print(
-        "app,stages,kernels,streams,linebuf,rings,eval_rows,vmem_kib,"
-        "hbm_kib,compile_us,run_us_interp,run_us_warm,max_err,verified,status"
+        "app,stages,kernels,streams,linebuf,rings,reductions,eval_rows,"
+        "vmem_kib,hbm_kib,compile_us,run_us_interp,run_us_warm,max_err,"
+        "verified,status"
     )
     ok = True
     for r in rows:
@@ -236,7 +242,7 @@ def main(argv=None) -> int:
         ok = ok and r["ok"]
         print(
             f"{r['app']},{r['stages']},{r['kernels']},{r['streams']},"
-            f"{r['linebuf']},{r['rings']},{r['eval_rows']},"
+            f"{r['linebuf']},{r['rings']},{r['reductions']},{r['eval_rows']},"
             f"{r['vmem_kib']},{r['hbm_kib']},{r['compile_us']},"
             f"{r['run_us_interp']},{r['run_us_warm']},{r['max_err']:.2e},"
             f"{r['verified']},{status}"

@@ -25,6 +25,7 @@ GOLDEN_PLAN_SHAPES: Dict[Tuple[str, Optional[str]], Tuple[int, int]] = {
     ("unsharp", None): (4, 1),
     ("camera", None): (5, 2),      # stride-2 demosaic pins denoise in HBM
     ("mobilenet", None): (2, 1),
+    ("efficientnet_b0_mbconv", None): (13, 1),   # one whole kernel
 }
 
 

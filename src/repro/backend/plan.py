@@ -78,7 +78,7 @@ from repro.core.ubplan import (
     lane_width_candidates,
     plan_affine_stage,
 )
-from repro.frontend.expr import BinOp, FuncRef, expr_depth, refs_in
+from repro.frontend.expr import BinOp, Const, FuncRef, expr_depth, refs_in
 from repro.frontend.lower import NormalizedStage, Pipeline, normalize_pipeline
 
 from .access import AxisAccess, LoadAccess, UnsupportedAccessError, decompose_stage
@@ -109,6 +109,9 @@ MAX_RED_CHUNK = 128
 # which is what makes short-grid strided rings (few steps to amortize the
 # warm-up against) lose to plain per-tap delivery under ``auto``.
 RING_STEP_OVERHEAD_CYCLES = 8
+
+# the ways a stage's reduction is emitted (StagePlan.reduction_kind)
+REDUCTION_KINDS = ("contraction", "block_reduce", "unrolled")
 
 
 def _tunable(default: object) -> Any:
@@ -469,6 +472,18 @@ class StagePlan:
     lane_axis_of: List[Optional[int]] = field(default_factory=list)
     # a reduction emitted as matrix products on the MXU (see Contraction)
     contraction: Optional["Contraction"] = None
+    # a reduction over every position of a member held whole (BlockReduce)
+    block_reduce: Optional["BlockReduce"] = None
+
+    @property
+    def reduction_kind(self) -> Optional[str]:
+        """How the stage's reduction is emitted: ``"contraction"``,
+        ``"block_reduce"`` or ``"unrolled"``; None without one."""
+        if not self.nstage.red_dims:
+            return None
+        if self.contraction is not None:
+            return "contraction"
+        return "block_reduce" if self.block_reduce is not None else "unrolled"
 
     @property
     def name(self) -> str:
@@ -612,6 +627,20 @@ class Contraction:
 
 
 @dataclass(frozen=True)
+class BlockReduce:
+    """A reduction over every position of a member held whole in VMEM,
+    emitted as one sum over the member's reduced axes instead of one
+    unrolled tap per reduction point.  The term is the member's load
+    ``load`` alone, or times the constant ``scale``; each reduction dim
+    reads one of the member's axes whole, at its full extent, and the
+    pure dims read the rest.  The sum is scaled once, then added to the
+    stage's init."""
+
+    load: int                         # load index of the member
+    scale: float = 1.0
+
+
+@dataclass(frozen=True)
 class ParamLayout:
     """How a parameter is laid out on the device.  ``axes[j]`` is the
     source axis that device axis ``j`` holds, or None for an inserted unit
@@ -698,6 +727,43 @@ def _contraction(
     return Contraction(lhs, rhs, dim, out_dim, taps)
 
 
+def _block_reduce(
+    ns: NormalizedStage, accesses: Sequence[LoadAccess],
+    held: Mapping[str, Tuple[int, ...]],
+) -> Optional[BlockReduce]:
+    """The stage's reduction as a :class:`BlockReduce`, or None where it
+    is not one.  ``held`` gives the extents of the kernel's members held
+    whole in VMEM (the stage itself excluded).  The term must be one load
+    of such a member, possibly times a constant; every axis of the load is
+    read whole by one dim, each reduction dim on one axis whose extent it
+    covers in full."""
+    v = ns.value
+    scale = 1.0
+    if isinstance(v, BinOp) and v.op == "mul":
+        ref = [t for t in (v.a, v.b) if isinstance(t, FuncRef)]
+        const = [t for t in (v.a, v.b) if isinstance(t, Const)]
+        if len(ref) != 1 or len(const) != 1:
+            return None
+        v, scale = ref[0], float(const[0].value)
+    if not (ns.red_dims and isinstance(v, FuncRef) and len(accesses) == 1):
+        return None
+    la = accesses[0]
+    ext = held.get(la.buffer)
+    if ext is None or la.buffer == ns.name or len(ext) != len(la.axes):
+        return None
+    reads = [_whole_axis(ax) for ax in la.axes]
+    if None in reads:
+        return None
+    reds = {d: j for j, (d, pure) in enumerate(reads) if not pure}
+    if sorted(reds) != sorted(ns.red_dims) or len(reds) != sum(
+        not pure for _d, pure in reads
+    ):
+        return None
+    if any(ns.extent(d) != ext[j] for d, j in reds.items()):
+        return None
+    return BlockReduce(0, scale)
+
+
 def _param_layouts(
     infos: Sequence[Tuple[NormalizedStage, List[LoadAccess], bool]],
     contractions: Mapping[str, Contraction],
@@ -728,6 +794,12 @@ def _param_layouts(
             elif None not in reads and all(p for _d, p in reads):
                 pos = {d: j for j, (d, _p) in enumerate(reads)}
                 axes = tuple(pos.get(d) for d in ns.pure_dims)
+            elif None not in reads and [d for d, p in reads if p] == [
+                ns.pure_dims[-1]
+            ]:
+                # a per-channel filter read by taps (a depthwise stage's)
+                # in a channels-last stage: taps first, channels on lanes
+                axes = tuple(sorted(range(len(reads)), key=lambda j: reads[j][1]))
             out[name] = ParamLayout(axes, tuple(
                 1 if a is None else shape[a] for a in axes
             ))
@@ -1790,6 +1862,17 @@ def _build_kernel_group(
                 raise
             lane_possible = False
 
+    # a whole kernel holds every member but the output whole in VMEM: a
+    # reduction over all of one such member is one block reduction
+    held = {} if kernel_streamed else {
+        ns.name: tuple(ns.pure_extents) for ns, _, _ in members[:-1]
+    }
+    block_reduces = {
+        ns.name: br for ns, acc, _ in members
+        if ns.name not in contractions
+        for br in [_block_reduce(ns, acc, held)] if br is not None
+    }
+
     def assemble(
         lb_names: Set[str], use_rings: bool, banned: Set[Tuple],
         bw: Optional[int] = None,
@@ -1802,6 +1885,7 @@ def _build_kernel_group(
             ns.name: StagePlan(
                 ns, list(acc), streamed and not whole,
                 contraction=contractions.get(ns.name),
+                block_reduce=block_reduces.get(ns.name),
             )
             for ns, acc, streamed in members
         }
@@ -2608,6 +2692,9 @@ def build_pipeline_plan(
             kg.batch_grid = bg
             kg.grid = (batch_capacity,) + kg.grid
         notes["batch_capacity"] = batch_capacity
+    # how many stages emit their reduction each way
+    kinds = [sp.reduction_kind for kg in kernels for sp in kg.stages]
+    notes["reductions"] = {k: kinds.count(k) for k in REDUCTION_KINDS}
     return PipelinePlan(pipe, nstages, kernels, notes=notes, params=layouts)
 
 
@@ -2623,6 +2710,7 @@ __all__ = [
     "StagePlan",
     "RedGrid",
     "Contraction",
+    "BlockReduce",
     "ParamLayout",
     "PaddedGrid",
     "PlanOptions",

@@ -456,11 +456,13 @@ def compile_pipeline(
                 assert_plan_verified(hit.plan)
             return hit
         _CACHE_STATS["misses"] += 1
-    plan = build_pipeline_plan(pipe, options=opts)
-    if opts.line_buffer is True:
-        _warn_lane_carry_degrades(plan)
-    if verify is not False:
-        assert_plan_verified(plan)
+    with tracing.span(tracing.PLAN) as span:
+        plan = build_pipeline_plan(pipe, options=opts)
+        if opts.line_buffer is True:
+            _warn_lane_carry_degrades(plan)
+        if verify is not False:
+            assert_plan_verified(plan)
+        span.set_metadata(**plan.notes["reductions"])
     params: Dict[str, jax.Array] = {}
     if plan.params:
         with tracing.span(tracing.PARAMS):

@@ -4,7 +4,9 @@ Every span is a ``jax.profiler.TraceAnnotation``: it costs about a
 microsecond with the profiler off and lands on the host plane of a
 ``jax.profiler`` trace with it on.  One span per dispatch and per phase,
 never per slot; the per-kernel launch span is named once, at emit time
-(``CompiledKernel.span``).
+(``CompiledKernel.span``).  Two spans belong to a compile, not a dispatch:
+``ub.plan`` (with the plan's count of each reduction kind as arguments)
+and ``ub.params``.
 """
 
 import jax
@@ -22,3 +24,4 @@ FINITE_CHECK = "ub.finite_check"  # read of the device's per-slot NaN/Inf flags
 RECOMPILE = "ub.recompile"        # recovery-ladder recompile
 QUARANTINE = "ub.quarantine"      # one bisection probe dispatch
 PARAMS = "ub.params"              # compile_pipeline: parameters onto the device, once
+PLAN = "ub.plan"                  # compile_pipeline: plan and verify; reduction-kind counts

@@ -41,6 +41,9 @@ step clobbers a row ring.
 the same output element except through a declared ``RedGrid`` accumulation;
 per-stage shift sets re-derived from the raw access maps match the planned
 ones, and the implied eval-row counts match ``KernelGroup.eval_rows()``.
+A block reduction reads a member held whole in the kernel's VMEM, every
+position of its reduced axes once per batch slot, with nothing carried
+(UB303).
 
 ``UB4xx`` — **budget audit**.  An independent re-summation of view, ring,
 scratch, and output bytes against ``vmem_bytes()``, and of the planner's
@@ -105,6 +108,8 @@ RULES: Dict[str, str] = {
              "lane) sweep exactly once; no row carry under a lane grid",
     "UB301": "exactly-once: extra grid dims are declared; rows cover the extent",
     "UB302": "eval accounting: derived shift sets and eval rows match the plan",
+    "UB303": "block reduction: a whole-held member, each reduced position "
+             "read once a slot, nothing carried",
     "UB401": "VMEM re-summation: stream/ring/scratch bytes match vmem_bytes()",
     "UB402": "VMEM budget: the working set fits the recorded budget",
     "UB403": "working-set drift: re-derived (bytes_per_row, fixed) match ws",
@@ -1106,6 +1111,58 @@ def _check_eval_accounting(kg: KernelGroup, out: List[PlanViolation]) -> None:
             ))
 
 
+def _check_block_reduces(kg: KernelGroup, out: List[PlanViolation]) -> None:
+    """UB303: a block reduction sums a member the kernel holds whole: the
+    kernel is whole (one grid step a batch slot, so no carry and no grid
+    dim walks the reduction), the load is a scratch load of an earlier
+    member whose panel the working set counts (``scratch_entries``, UB401),
+    and each reduction dim reads one of the member's axes alone, at
+    coefficient 1 and offset 0, over its full extent — so every position
+    is read once a slot, which UB302/UB503's one evaluation a slot makes
+    exact."""
+    held = {sp.name for sp, _key in kg.scratch_entries()}
+    for sp in kg.stages:
+        br = sp.block_reduce
+        if br is None:
+            continue
+
+        def bad(msg: str, witness: Tuple[int, ...] = ()) -> None:
+            out.append(PlanViolation(
+                "UB303", kg.name, msg, stage=sp.name, witness=witness,
+            ))
+
+        if kg.streamed or kg.red_grid is not None or sp.contraction is not None:
+            bad("block reduction outside a whole kernel (or beside a grid "
+                "reduction or a contraction): its member is not held whole")
+            continue
+        if not 0 <= br.load < len(sp.accesses) or sp.load_kind[br.load] != "scratch":
+            bad(f"load {br.load} is no scratch load of a member")
+            continue
+        pname = sp.scratch_producer[br.load]
+        if pname not in held:
+            bad(f"member {pname!r} is not held in the kernel's VMEM scratch")
+            continue
+        pext = kg.stage_plan(pname).nstage.pure_extents
+        red = dict(zip(sp.nstage.red_dims, sp.nstage.red_extents))
+        seen: List[str] = []
+        for j, ax in enumerate(sp.accesses[br.load].axes):
+            if not ax.red_coeffs:
+                continue
+            (d, c), *rest = ax.red_coeffs
+            if rest or c != 1 or ax.const != 0 or ax.pure_dim is not None:
+                bad(f"axis {j} of {pname!r} is not read whole by one "
+                    f"reduction dim", witness=(j,))
+                continue
+            seen.append(d)
+            if j >= len(pext) or red[d] != pext[j]:
+                bad(f"reduction dim {d!r} reads {red[d]} positions of axis "
+                    f"{j} of {pname!r}, not its full extent",
+                    witness=(red[d], pext[j] if j < len(pext) else -1))
+        if sorted(seen) != sorted(red):
+            bad(f"reduction dims {sorted(red)} do not each read one axis of "
+                f"{pname!r} (read: {sorted(seen)})")
+
+
 # ---------------------------------------------------------------------------
 # UB4xx — budget audit
 # ---------------------------------------------------------------------------
@@ -1405,6 +1462,7 @@ def verify_plan(plan: PipelinePlan) -> List[PlanViolation]:
         _check_red_grid(kg, out)
         _check_write_once(kg, out)
         _check_eval_accounting(kg, out)
+        _check_block_reduces(kg, out)
         _check_batch(kg, plan.notes, out)
         _check_params(kg, shapes, set(plan.params), out)
         _check_budget(kg, budget, out)

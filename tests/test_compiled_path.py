@@ -258,8 +258,9 @@ def test_plan_cache_key_normalizes_default_kwargs(option):
 
 # the plan cache key of each benchmark cell's served compile
 # (chipbench/configs/<cell>.json: make_app arguments and batch_slots),
-# recorded before PlanOptions replaced the keyword dicts: the same key
-# names the same plan, so a change here changes what the cells run
+# recorded before PlanOptions replaced the keyword dicts (the EfficientNet
+# block's when its cell was added): the same key names the same plan, so a
+# change here changes what the cells run
 SERVED_KEYS = {
     "blur_1080p": (
         "gaussian", dict(size=1082, width=1922), 4,
@@ -274,6 +275,13 @@ SERVED_KEYS = {
         dict(img=56, cin=256, mid=64, weight_seed=1512, act_scale=0.015625),
         8,
         "cc24fea2b20c978973e75b147a4adc9dc42acc0b78da19aa035f911de843f2b1",
+    ),
+    "efficientnet_b0_mbconv6_56": (
+        "efficientnet_b0_mbconv",
+        dict(img=56, cin=24, expand=6, se_ratio=0.25, kernel=3,
+             weight_seed=1905, act_scale=0.03125),
+        64,
+        "f0328b394a491f1c7671cebf5ce76d77732375ffabad87c907ccb6ea2c477014",
     ),
 }
 

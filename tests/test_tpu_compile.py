@@ -51,12 +51,11 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile_all(pp, sharding):
-    """Lower and compile every kernel of ``pp`` on ``sharding``'s device;
-    return the compiled executables."""
+def _lowered(pp, sharding):
+    """``(kernel, lowering)`` of every kernel of ``pp``, lowered against
+    its buffers' shapes on ``sharding``'s device."""
     cap = pp.plan.notes.get("batch_capacity")
     lead = (cap,) if cap else ()
-    out = []
     for ck in pp.kernels:
         assert ck.mode == "compiled"
         args = tuple(
@@ -66,7 +65,14 @@ def _compile_all(pp, sharding):
             )
             for b in ck.buffer_order
         )
-        lowered = ck.jitted.lower(args)
+        yield ck, ck.jitted.lower(args)
+
+
+def _compile_all(pp, sharding):
+    """Lower and compile every kernel of ``pp`` on ``sharding``'s device;
+    return the compiled executables."""
+    out = []
+    for ck, lowered in _lowered(pp, sharding):
         assert "tpu_custom_call" in lowered.as_text(), ck.name
         out.append(lowered.compile())
     return out
@@ -124,17 +130,7 @@ def _mosaic_bodies(lowered_text):
 def _compile_contractions(pp, sharding):
     """Every kernel of ``pp`` compiles, and its Mosaic module holds the
     matmul op the channel contractions lower to."""
-    cap = pp.plan.notes.get("batch_capacity")
-    lead = (cap,) if cap else ()
-    for ck in pp.kernels:
-        args = tuple(
-            jax.ShapeDtypeStruct(
-                lead + tuple(pp.pipeline.buffer_boxes[b].extents),
-                jnp.float32, sharding=sharding,
-            )
-            for b in ck.buffer_order
-        )
-        lowered = ck.jitted.lower(args)
+    for ck, lowered in _lowered(pp, sharding):
         bodies = _mosaic_bodies(lowered.as_text())
         assert bodies and all(b"matmul" in b for b in bodies), ck.name
         lowered.compile()
@@ -160,4 +156,47 @@ def test_resnet_layer_with_bound_weights_compiles_for_v5e(one_chip):
     app = make_app("resnet", img=56, cin=64, cout=64, weights=w)
     pp = compile_pipeline(app.pipeline, mode="compiled")
     assert pp.kernels[0].kg.output.contraction is not None
+    _compile_contractions(pp, one_chip)
+
+
+def test_efficientnet_mbconv_block_compiles_for_v5e(one_chip):
+    """The EfficientNet-B0 MBConv6 block at its published widths (56 x 56,
+    24 -> 144 -> 24, depthwise 3 x 3, squeeze 6), served at its cell's 64
+    batch slots: one whole-image kernel whose two 1 x 1 convolutions and
+    two excitation layers (unit rows) are MXU matrix products and whose
+    squeeze is one block reduction (about 12 s here)."""
+    app = make_app("efficientnet_b0_mbconv")
+    server = PipelineServer(app.pipeline, batch_slots=64, mode="compiled")
+    pp = server.pipeline
+    [ck] = pp.kernels
+    assert ck.buffer_order == ("ifmap",)            # weights are bound
+    assert pp.plan.notes["reductions"] == {
+        "contraction": 4, "block_reduce": 1, "unrolled": 1}
+    _compile_contractions(pp, one_chip)
+
+
+def test_efficientnet_squeeze_lowers_to_one_block_sum(one_chip):
+    """At 8 slots the block's lowered kernel is under 3x the size of the
+    same block without squeeze-and-excitation; the squeeze unrolled over
+    its 3,136 pixels made it about 31x."""
+    size = {}
+    for se_ratio in (0.25, 0):
+        app = make_app("efficientnet_b0_mbconv", se_ratio=se_ratio)
+        pp = PipelineServer(app.pipeline, batch_slots=8, mode="compiled").pipeline
+        [(_ck, lowered)] = _lowered(pp, one_chip)
+        size[se_ratio] = len(lowered.as_text())
+    assert size[0.25] < 3 * size[0], size
+
+
+def test_mobilenet_layer_with_bound_weights_compiles_for_v5e(one_chip):
+    """The paper's depthwise-separable layer at 56 x 56, 32 -> 32
+    channels, its weights parameters: the depthwise stage unrolled over
+    its nine taps and the pointwise stage one MXU contraction, in one
+    kernel (about a second here)."""
+    rng = np.random.default_rng(0)
+    w = (rng.standard_normal((32, 3, 3)), rng.standard_normal((32, 32)))
+    app = make_app("mobilenet", img=56, cin=32, cout=32, weights=w)
+    pp = compile_pipeline(app.pipeline, mode="compiled")
+    assert pp.plan.notes["reductions"] == {
+        "contraction": 1, "block_reduce": 0, "unrolled": 1}
     _compile_contractions(pp, one_chip)
